@@ -81,8 +81,8 @@ def main(fast: bool = False) -> list[str]:
         per = t // ps
         ks = jax.random.split(jax.random.key(1), 4)
         q = jax.random.normal(ks[0], (b, hq, d), jnp.float32)
-        kp = jax.random.normal(ks[1], (b * per, ps, hkv, d), jnp.float32)
-        vp = jax.random.normal(ks[2], (b * per, ps, hkv, d), jnp.float32)
+        kp = jax.random.normal(ks[1], (b * per, hkv, ps, d), jnp.float32)
+        vp = jax.random.normal(ks[2], (b * per, hkv, ps, d), jnp.float32)
         pt = jax.random.permutation(ks[3], b * per).reshape(b, per)
         pt = pt.astype(jnp.int32)
         pos = jnp.full((b,), t - 1, jnp.int32)

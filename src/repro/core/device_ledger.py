@@ -17,7 +17,7 @@ on intra-batch slot collisions (numpy fancy-assignment order).
 Sharding: ``repro.distributed.ledger`` maps these ops over the data axes
 with each shard owning a slice of the table, so capacity scales with the
 mesh instead of host RAM. The fused ``record_priority`` additionally has a
-Pallas kernel (``repro.kernels.ledger``), dispatched via ``impl=``.
+Pallas kernel (``repro.kernels.ledger``), taken by default on a TPU.
 """
 
 from __future__ import annotations
@@ -291,14 +291,14 @@ def record_priority(
     Equivalent to ``record`` (honoring the optional ``valid`` write mask
     and the optional ``signals`` channels) followed by ``priority`` over
     ALL ids at the same step, in one pass (one hash, one table visit).
-    ``impl`` selects the backend as in ``repro.kernels.ops`` ("ref" = the
-    jnp path below, "pallas"/"interpret" = the fused Pallas kernel; the
-    kernel covers the four scalar-channel arrays and the ``sig`` channels
-    ride the jnp scatter alongside it).
+    ``impl`` overrides the platform's backend as in ``repro.kernels.ops``
+    ("ref" = the jnp path below, "pallas"/"interpret" = the fused Pallas
+    kernel, the default on a TPU; the kernel covers the four scalar-channel
+    arrays and the ``sig`` channels ride the jnp scatter alongside it).
     """
-    if impl not in (None, "ref"):
-        from repro.kernels import ops as kops
+    from repro.kernels import ops as kops
 
+    if kops.resolve(impl) != "ref":
         sig = _sig_scatter(cfg, state, ids, signals, valid)
         ema, count, last_seen, owner, pri = kops.ledger_record_priority(
             state.ema,

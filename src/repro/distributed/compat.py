@@ -1,25 +1,14 @@
-"""Version shims for the JAX APIs this repo straddles.
+"""The one place this repo calls ``jax.shard_map`` and mesh-axis queries.
 
-``shard_map`` moved from ``jax.experimental.shard_map`` (where its
-replication-check kwarg is ``check_rep``) to ``jax.shard_map`` (where it is
-``check_vma``), and ``jax.lax.axis_size`` only exists on the newer line.
-Every call site in this repo goes through the shims below so both API
-generations work; do not call ``jax.shard_map``/``jax.lax.axis_size``
-directly.
+Call sites go through :func:`shard_map` (a fixed keyword signature with the
+replication check off by default) and :func:`linear_axis_index` instead of
+spelling the JAX calls out each time.
 """
 
 from __future__ import annotations
 
 import jax
-
-
-def axis_size(name: str) -> int:
-    """Static size of a bound mesh axis (inside shard_map)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    from jax._src import core as _core  # jax <= 0.4.x
-
-    return _core.axis_frame(name)
+import jax.numpy as jnp
 
 
 def linear_axis_index(axes):
@@ -28,26 +17,13 @@ def linear_axis_index(axes):
     (``all_gather(..., tiled=True)``) and of a global batch sharded over
     the same axes — the alignment both shard-local selection and ledger
     routing depend on."""
-    import jax.numpy as jnp
-
     idx = jnp.zeros((), jnp.int32)
     for a in axes:
-        idx = idx * axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
-if hasattr(jax, "shard_map"):
 
-    def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check,
-        )
-
-else:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check,
-        )
+def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check,
+    )

@@ -24,7 +24,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.compat import axis_size
 
 Array = jax.Array
 F32 = jnp.float32
@@ -61,7 +60,7 @@ def int8_ring_all_reduce(x: Array, axis_name: str, chunk: int = 256) -> Array:
     quantized contribution around the ring; each rank dequantizes and sums
     the P contributions in rank order (identical result on all ranks).
     """
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     if p == 1:
         return x
     shape, dtype = x.shape, x.dtype

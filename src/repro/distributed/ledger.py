@@ -168,16 +168,15 @@ def exchange_bytes_per_op(
     return n + (gather_round if overflow else 0)
 
 
-def _host_span(name: str, **args):
+def _host_span(name: str, ids, **args):
     """A telemetry span only when dispatching from host Python. These ops
     also trace INSIDE fused jits (the engine step / train step call
     ``record`` through ``recorder.score_one``), where opening a span would
-    time the trace once and record nothing at run time — a traced call
-    gets the shared null span instead."""
-    clean = getattr(jax.core, "trace_state_clean", None)
-    if clean is None or clean():
-        return obs.span(name, cat="ledger", **args)
-    return obs.NULL_SPAN
+    time the trace once and record nothing at run time — a call whose
+    ``ids`` are a tracer gets the shared null span instead."""
+    if isinstance(ids, jax.core.Tracer):
+        return obs.NULL_SPAN
+    return obs.span(name, cat="ledger", **args)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -491,6 +490,7 @@ class ShardedLedgerOps:
         args = (state, ids, losses, valid) + ((signals,) if has_sig else ())
         with _host_span(
             "ledger.record",
+            ids,
             exchange=self.exchange if self.route else "pinned",
             shards=self.shards,
         ):
@@ -521,7 +521,7 @@ class ShardedLedgerOps:
             )
 
         fn = self._wrap(local, 1, (dp, dp))
-        with _host_span("ledger.lookup", shards=self.shards):
+        with _host_span("ledger.lookup", ids, shards=self.shards):
             return fn(state, ids, jnp.zeros((), I32))
 
     def lookup_signals(self, state: LedgerState, ids):
@@ -549,7 +549,7 @@ class ShardedLedgerOps:
             )
 
         fn = self._wrap(local, 1, (dp, dp, dp))
-        with _host_span("ledger.lookup_signals", shards=self.shards):
+        with _host_span("ledger.lookup_signals", ids, shards=self.shards):
             return fn(state, ids, jnp.zeros((), I32))
 
     def priority(self, state: LedgerState, ids, step):
@@ -570,7 +570,7 @@ class ShardedLedgerOps:
             return self._return_route(pri, mine, b)
 
         fn = self._wrap(local, 1, dp)
-        with _host_span("ledger.priority", shards=self.shards):
+        with _host_span("ledger.priority", ids, shards=self.shards):
             return fn(state, ids, jnp.asarray(step, I32))
 
     def record_priority(
@@ -619,6 +619,7 @@ class ShardedLedgerOps:
         args = (state, ids, losses, valid) + ((signals,) if has_sig else ())
         with _host_span(
             "ledger.record_priority",
+            ids,
             exchange=self.exchange if self.route else "pinned",
             shards=self.shards,
         ):

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.distributed.compat import axis_size, shard_map
+from repro.distributed.compat import shard_map
 
 Array = jax.Array
 
@@ -41,7 +41,7 @@ def pipeline_apply(
     """Run the pipeline; every stage returns the final outputs [n_micro, ...]
     (identical on all stages — the last stage's results are broadcast back
     through the same ring, costing one extra ring pass)."""
-    n_stages = axis_size(axis)
+    n_stages = jax.lax.axis_size(axis)
     stage = jax.lax.axis_index(axis)
     n_micro = micro.shape[0]
     ticks = n_micro + n_stages - 1

@@ -8,16 +8,17 @@ decode_attn — flash decode attention: the serving forward whose losses
 ssd         — Mamba2 chunk scan (assigned ssm/hybrid architectures).
 ledger      — fused recycle-ledger record+priority: one VMEM residency per
               batch for the device ledger's hash + EMA scatter + score
-              (repro.core.device_ledger dispatches here via impl=).
+              (repro.core.device_ledger dispatches here on a TPU).
 
 Each kernel: <name>.py (pl.pallas_call + BlockSpec), ref.py oracle entry,
-ops.py jit'd wrapper with backend dispatch + custom_vjp.
+ops.py jit'd wrapper with backend dispatch + custom_vjp. ``default_impl``
+is the one dispatch rule: the Pallas kernels on a TPU, the jnp oracle
+elsewhere.
 """
 
 from repro.kernels import ops  # noqa: F401
 from repro.kernels.ops import (  # noqa: F401
-    get_default_impl,
-    set_default_impl,
+    default_impl,
     ssd_scan,
     xent_loss,
 )

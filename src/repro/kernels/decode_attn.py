@@ -114,8 +114,8 @@ def _paged_decode_kernel(
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attn(
     q: jax.Array,  # [B, Hq, D]
-    kp: jax.Array,  # [P, page, Hkv, D] global page pool
-    vp: jax.Array,  # [P, page, Hkv, D]
+    kp: jax.Array,  # [P, Hkv, page, D] global page pool (head-major)
+    vp: jax.Array,  # [P, Hkv, page, D]
     page_table: jax.Array,  # [B, NP] i32, -1 = unallocated
     pos: jax.Array,  # [B] i32 per-slot depth (position pos is attended)
     *,
@@ -127,9 +127,12 @@ def paged_decode_attn(
     the K/V BlockSpec index maps can address physical pages — each grid
     step DMAs exactly one page; no [B, T, ...] dense gather ever
     materializes. Grid (B, Hkv, NP), pages minor, online-softmax state in
-    VMEM scratch exactly like :func:`decode_attn`."""
+    VMEM scratch exactly like :func:`decode_attn`. The pool is head-major
+    so each K/V block (one page of one kv head) spans the array's last two
+    dims whole, the block shape the TPU lowering accepts at any page size
+    and head dim."""
     b, hq, d = q.shape
-    p_, page, hkv, _ = kp.shape
+    p_, hkv, page, _ = kp.shape
     npg = page_table.shape[1]
     g = hq // hkv
     qr = q.reshape(b, hkv, g, d)
@@ -146,12 +149,12 @@ def paged_decode_attn(
             # physical page via the prefetched table; -1 clamps to page 0
             # for the DMA and the kernel masks the whole block
             pl.BlockSpec(
-                (None, page, None, d),
-                lambda i, j, pi, pt, ps: (jnp.maximum(pt[i, pi], 0), 0, j, 0),
+                (None, None, page, d),
+                lambda i, j, pi, pt, ps: (jnp.maximum(pt[i, pi], 0), j, 0, 0),
             ),
             pl.BlockSpec(
-                (None, page, None, d),
-                lambda i, j, pi, pt, ps: (jnp.maximum(pt[i, pi], 0), 0, j, 0),
+                (None, None, page, d),
+                lambda i, j, pi, pt, ps: (jnp.maximum(pt[i, pi], 0), j, 0, 0),
             ),
         ],
         out_specs=pl.BlockSpec(

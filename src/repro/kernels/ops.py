@@ -1,11 +1,10 @@
 """Public kernel entry points: backend dispatch + autodiff.
 
-Each op picks its implementation from (in priority order)
-  1. an explicit ``impl=`` argument,
-  2. the module default set by ``set_default_impl`` (the launcher sets
-     "pallas" on TPU hosts),
-  3. "ref" — the pure-jnp oracle, the right default on CPU where Pallas-TPU
-     kernels only run under interpret=True (orders of magnitude slower).
+One rule picks each op's implementation (:func:`default_impl`): the
+Pallas kernel when JAX's default backend is a TPU, the pure-jnp "ref"
+oracle everywhere else. An explicit ``impl=`` overrides it — tests use it
+to run a kernel under "interpret" on the CPU or to compare against "ref";
+no path on the TPU falls back to either by default.
 
 ``xent_loss`` carries a custom_vjp: forward saves only the [T] LSE (never a
 [T, V] softmax); backward recomputes grad blockwise from (logits, lse).
@@ -26,23 +25,21 @@ from repro.kernels import ssd as _ssd
 from repro.kernels import topk_lse as _topk
 from repro.kernels import xent as _xent
 
-_DEFAULT_IMPL = "ref"
 _VALID = ("ref", "pallas", "interpret")
 
 
-def set_default_impl(impl: str) -> None:
-    global _DEFAULT_IMPL
-    assert impl in _VALID, impl
-    _DEFAULT_IMPL = impl
+def default_impl() -> str:
+    """The platform's implementation: "pallas" on a TPU, "ref" elsewhere
+    (Pallas-TPU kernels run on the CPU only under the interpreter, which
+    is orders of magnitude slower than the jnp oracle)."""
+    return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
-def get_default_impl() -> str:
-    return _DEFAULT_IMPL
-
-
-def _resolve(impl: Optional[str]) -> str:
-    impl = impl or _DEFAULT_IMPL
-    assert impl in _VALID, impl
+def resolve(impl: Optional[str]) -> str:
+    """``impl`` if given, else :func:`default_impl`."""
+    impl = impl or default_impl()
+    if impl not in _VALID:
+        raise ValueError(f"kernel impl {impl!r} not in {_VALID}")
     return impl
 
 
@@ -54,7 +51,7 @@ def _resolve(impl: Optional[str]) -> str:
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def xent_loss(logits: jax.Array, labels: jax.Array, impl: Optional[str] = None):
     """Per-token CE: logits [T,V], labels [T] -> loss [T] f32."""
-    loss, _ = _xent_fwd_impl(logits, labels, _resolve(impl))
+    loss, _ = _xent_fwd_impl(logits, labels, resolve(impl))
     return loss
 
 
@@ -65,13 +62,13 @@ def _xent_fwd_impl(logits, labels, impl):
 
 
 def _xent_fwd(logits, labels, impl):
-    loss, lse = _xent_fwd_impl(logits, labels, _resolve(impl))
+    loss, lse = _xent_fwd_impl(logits, labels, resolve(impl))
     return loss, (logits, labels, lse)
 
 
 def _xent_bwd(impl, res, g):
     logits, labels, lse = res
-    impl = _resolve(impl)
+    impl = resolve(impl)
     if impl == "ref":
         grad = _ref.xent_grad_ref(logits, labels, lse, g)
     else:
@@ -95,7 +92,7 @@ def topk_lse(
     """Compress logits [T,V] into the retained-outcome summary:
     (top-k values [T,k] f32 descending, top-k indices [T,k] i32,
     exact lse [T] f32). One streaming pass on the Pallas path."""
-    impl = _resolve(impl)
+    impl = resolve(impl)
     if impl == "ref":
         return _ref.topk_lse_ref(logits, k)
     return _topk.topk_lse(logits, k, interpret=(impl == "interpret"))
@@ -113,7 +110,7 @@ def decode_attn(
     valid: jax.Array,
     impl: Optional[str] = None,
 ) -> jax.Array:
-    impl = _resolve(impl)
+    impl = resolve(impl)
     if impl == "ref":
         return _ref.decode_attn_ref(q, k, v, valid)
     return _da.decode_attn(q, k, v, valid, interpret=(impl == "interpret"))
@@ -129,8 +126,8 @@ def paged_decode_attn(
 ) -> jax.Array:
     """Decode attention through the paged KV pool (see
     ``kernels.decode_attn.paged_decode_attn``): q [B,Hq,D], pool
-    [P,page,Hkv,D], page_table [B,NP] (-1 = unallocated), pos [B]."""
-    impl = _resolve(impl)
+    [P,Hkv,page,D], page_table [B,NP] (-1 = unallocated), pos [B]."""
+    impl = resolve(impl)
     if impl == "ref":
         return _ref.paged_decode_attn_ref(q, kp, vp, page_table, pos)
     return _da.paged_decode_attn(
@@ -175,7 +172,7 @@ def ledger_record_priority(
     takes the two-pass block-parallel tiling, below it the single-program
     fori loop); "fori"/"block" force one.
     """
-    impl = _resolve(impl)
+    impl = resolve(impl)
     if impl == "ref":
         return _ref.ledger_record_priority_ref(
             ema, count, last_seen, owner, ids, losses, step,
@@ -207,7 +204,7 @@ def ssd_scan(
     chunk: int = 128,
     impl: Optional[str] = None,
 ) -> tuple[jax.Array, jax.Array]:
-    impl = _resolve(impl)
+    impl = resolve(impl)
     if impl == "ref":
         from repro.models.ssm import ssd_chunked  # chunked jnp (fast ref path)
 

@@ -63,8 +63,8 @@ def decode_attn_ref(
 
 def paged_decode_attn_ref(
     q: Array,  # [B, Hq, D]
-    kp: Array,  # [P, page, Hkv, D] global page pool
-    vp: Array,  # [P, page, Hkv, D]
+    kp: Array,  # [P, Hkv, page, D] global page pool (head-major pages)
+    vp: Array,  # [P, Hkv, page, D]
     page_table: Array,  # [B, NP] i32 physical page per logical block
     pos: Array,  # [B] i32 per-slot depth; position pos is attended
 ) -> Array:
@@ -76,11 +76,11 @@ def paged_decode_attn_ref(
     are clamped to page 0 — whatever is read there is masked, and masked
     scores contribute exactly-zero softmax weight."""
     b = q.shape[0]
-    p_, page, hkv, d = kp.shape
+    p_, hkv, page, d = kp.shape
     t = page_table.shape[1] * page
     pt = jnp.maximum(page_table, 0)
-    k = kp[pt].reshape(b, t, hkv, d)
-    v = vp[pt].reshape(b, t, hkv, d)
+    k = kp[pt].swapaxes(2, 3).reshape(b, t, hkv, d)
+    v = vp[pt].swapaxes(2, 3).reshape(b, t, hkv, d)
     valid = jnp.arange(t)[None] <= pos[:, None]
     return decode_attn_ref(q, k, v, valid)
 

@@ -77,7 +77,10 @@ def _topk_lse_kernel(
     def pick(j, carry):
         cv, nvals, nidx = carry
         top = jnp.max(cv, axis=1, keepdims=True)  # [bt, 1]
-        am = jnp.argmax(cv, axis=1).astype(I32)[:, None]
+        # the first position holding the max, by a min over positions: the
+        # TPU's own arg-max reduction does not promise which of equal
+        # values it returns
+        am = jnp.min(jnp.where(cv == top, cpos, cw), axis=1, keepdims=True)
         winner = cpos == am  # [bt, cw] one-hot
         gi = jnp.sum(jnp.where(winner, comb_i, 0), axis=1, keepdims=True)
         write = opos == j

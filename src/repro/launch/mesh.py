@@ -44,27 +44,23 @@ def production_rules(*, multi_pod: bool = False) -> AxisRules:
 
 
 def make_elastic_mesh(
-    devices: Optional[Sequence] = None, model_parallel: int = 0
+    devices: Optional[Sequence] = None, model_parallel: int = 1
 ) -> Mesh:
     """Best (data, model) mesh from the devices that are actually up.
 
-    `model_parallel` pins the TP degree (0 = pick the largest power of two
-    <= 16 dividing the device count); the DP degree absorbs the rest, so a
-    job restarted with fewer healthy hosts keeps running (smaller batch or
-    more grad accumulation — the train loop recomputes per-shard batch).
+    `model_parallel` pins the TP degree; the DP degree absorbs the rest, so
+    a job restarted with fewer healthy hosts keeps running (smaller batch
+    or more grad accumulation — the train loop recomputes per-shard batch).
+    The default, pure data parallelism, gives every device its own "data"
+    shard: the axis the batch, the FSDP weights and the sharded recycle
+    ledger split over.
     """
-    devices = list(devices if devices is not None else jax.devices())
-    n = len(devices)
-    if model_parallel <= 0:
-        model_parallel = 1
-        while (
-            model_parallel * 2 <= min(16, n) and n % (model_parallel * 2) == 0
-        ):
-            model_parallel *= 2
-    if n % model_parallel:
-        raise ValueError(f"{n} devices not divisible by TP={model_parallel}")
     import numpy as np
 
+    devices = list(devices if devices is not None else jax.devices())
+    n = len(devices)
+    if model_parallel < 1 or n % model_parallel:
+        raise ValueError(f"{n} devices not divisible by TP={model_parallel}")
     arr = np.asarray(devices).reshape(n // model_parallel, model_parallel)
     return Mesh(arr, ("data", "model"))
 

@@ -36,6 +36,7 @@ import numpy as np
 from repro import configs, obs
 from repro.core.history import HistoryConfig
 from repro.data import DataConfig, SyntheticLMStream
+from repro.launch import use_compile_cache
 from repro.launch.mesh import make_elastic_mesh
 from repro.models import model as Mdl
 from repro.models.params import materialize
@@ -118,7 +119,8 @@ def submit_stream(engine, args, cfg):
     return waves, submitted
 
 
-def main(argv=None) -> int:
+def parser() -> argparse.ArgumentParser:
+    """The serve CLI's flags (also how ``chip_smoke.py`` builds its args)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b")
     ap.add_argument("--smoke", action="store_true")
@@ -148,13 +150,14 @@ def main(argv=None) -> int:
                     help="nucleus sampling mass (only with --temperature>0)")
     ap.add_argument("--instance-pool", type=int, default=1 << 20,
                     help="distinct stream instance ids before reuse")
-    ap.add_argument("--retain", default="full", choices=("full", "topk"),
-                    help="retained-outcome layout: the dense [slots,gen,V] "
-                         "logits buffer (exact oracle) or the compressed "
-                         "(top-k values/indices, exact lse) summary — "
-                         "constant size in V; late labels score exactly on "
-                         "a top-k hit, at the lse-min(topk) tail floor on "
-                         "a miss")
+    ap.add_argument("--retain", default="topk", choices=("full", "topk"),
+                    help="retained-outcome layout: the compressed (top-k "
+                         "values/indices, exact lse) summary — constant "
+                         "size in V; late labels score exactly on a top-k "
+                         "hit, at the lse-min(topk) tail floor on a miss — "
+                         "or the dense [slots,gen,V] logits buffer, the "
+                         "exact oracle (~10 GB at 32 slots x 512 tokens of "
+                         "a 152k vocab)")
     ap.add_argument("--topk", type=int, default=64,
                     help="retained top-k width under --retain topk")
     ap.add_argument("--ledger", default="host", choices=("host", "device"),
@@ -187,7 +190,12 @@ def main(argv=None) -> int:
                     help="write a run summary (throughput, records, ledger "
                          "stats) as JSON")
     obs.add_cli_args(ap)
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None) -> int:
+    args = parser().parse_args(argv)
+    use_compile_cache()
     if args.requests <= 0:
         args.requests = 3 * args.batch
 

@@ -49,6 +49,7 @@ from repro.core.selection import (
 from repro.data import DataConfig, Prefetcher, RecycleFeed, SyntheticLMStream
 from repro.distributed.ledger import sharded_ledger_ops
 from repro.distributed.sharding import DEFAULT_RULES, use_rules
+from repro.launch import use_compile_cache
 from repro.launch.mesh import make_elastic_mesh, validate_batch
 from repro.launch.specs import state_specs
 from repro.models import model as Mdl
@@ -134,11 +135,14 @@ def main(argv=None) -> int:
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--resume", default="", help="'auto' or a step number")
-    ap.add_argument("--model-parallel", type=int, default=0)
+    ap.add_argument("--model-parallel", type=int, default=1,
+                    help="tensor-parallel degree (the \"model\" mesh axis); "
+                         "the rest of the devices form the \"data\" axis")
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     obs.add_cli_args(ap)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
     telem = obs.from_args(args)
@@ -371,7 +375,13 @@ def main(argv=None) -> int:
             "step_ms_ema": (watchdog.ema or 0.0) * 1e3,
         }
 
-    with use_rules(mesh, rules):
+    if not single_device:
+        # start from the placement the step returns, so step 1 reuses step
+        # 0's executable instead of compiling the whole step again
+        state = jax.device_put(state, state_sh)
+    # one device has nothing to constrain: sharding hints there would only
+    # tag the step's outputs with a mesh its inputs lack (a second compile)
+    with use_rules(None if single_device else mesh, rules):
         for step in range(start_step, args.steps):
             t0 = time.time()
             raw = feed.batch(step)
@@ -475,6 +485,7 @@ def main(argv=None) -> int:
                      else "none"),
         "capacity_factor": args.capacity_factor,
         "a2a_overflow": a2a_overflow,
+        "ledger_shards": led_ops.shards if led_ops is not None else 1,
         "stragglers": watchdog.flagged,
         "ledger_hits_first": hits_log[0] if hits_log else None,
         "ledger_hits_mean": float(np.mean(hits_log)) if hits_log else None,

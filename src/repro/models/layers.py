@@ -332,13 +332,15 @@ def gqa_decode(
 def gqa_paged_init_cache(
     cfg: ModelConfig, num_pages: int, page_size: int, dtype
 ) -> dict:
-    """One layer's slice of the global KV page pool: [P, page, kv, hd].
+    """One layer's slice of the global KV page pool: [P, kv, page, hd].
 
     Unlike ``gqa_init_cache`` there is no per-slot reservation — physical
     pages are a shared pool, and a per-slot page table (held by the
-    serving engine's state, not the cache) maps logical block -> page."""
+    serving engine's state, not the cache) maps logical block -> page.
+    Head-major pages make one (page, kv-head) block the pool's last two
+    dims, the tile the TPU kernel's BlockSpec can address."""
     kv, hd = cfg.num_kv_heads, cfg.head_dim
-    shape = (num_pages, page_size, kv, hd)
+    shape = (num_pages, kv, page_size, hd)
     return {"kp": jnp.zeros(shape, dtype), "vp": jnp.zeros(shape, dtype)}
 
 
@@ -348,7 +350,7 @@ def gqa_paged_decode(
 ) -> tuple[Array, dict]:
     """Single-token decode through the paged KV pool.
 
-    x [B,1,D]; cache {"kp","vp": [P, page, kv, hd]}; page_table [B, NP]
+    x [B,1,D]; cache {"kp","vp": [P, kv, page, hd]}; page_table [B, NP]
     (physical page per logical block, -1 = unallocated — a write through
     an unallocated entry is DROPPED, so a freed slot can never scribble on
     a page that was reallocated to someone else); pos [B] per-slot depth.
@@ -356,12 +358,13 @@ def gqa_paged_decode(
     The ref path gathers the slot's pages back into the dense [B, T, ...]
     layout and runs the exact ``gqa_decode`` einsum chain (``_gqa_core``),
     so a paged engine at temperature 0 is BIT-identical to the dense one;
-    the pallas/interpret path streams pages through
-    ``kernels.ops.paged_decode_attn`` without materializing [B, T, ...].
+    the Pallas path (on a TPU, ``kernels.ops.default_impl``) streams pages
+    through ``kernels.ops.paged_decode_attn`` without materializing
+    [B, T, ...].
     """
     from repro.kernels import ops as kops
 
-    ps = cache["kp"].shape[1]
+    ps = cache["kp"].shape[2]
     npages = page_table.shape[1]
     t = npages * ps
     b = x.shape[0]
@@ -373,20 +376,25 @@ def gqa_paged_decode(
     # wrap numpy-style BEFORE mode="drop" filters, so a raw -1 would
     # scribble on the pool's last page instead of dropping
     page = jnp.where(page >= 0, page, cache["kp"].shape[0])
+    # [P, kv, page, hd] indexed (page, :, off): the advanced indices are
+    # split by a slice, so the update rows are [B, kv, hd] — k[:, 0] as is
     new_cache = {
-        "kp": cache["kp"].at[page, off].set(k[:, 0], mode="drop"),
-        "vp": cache["vp"].at[page, off].set(v[:, 0], mode="drop"),
+        "kp": cache["kp"].at[page, :, off].set(k[:, 0], mode="drop"),
+        "vp": cache["vp"].at[page, :, off].set(v[:, 0], mode="drop"),
     }
-    impl = kops.get_default_impl()
+    impl = kops.default_impl()
     if impl == "ref":
         # gather-to-dense + the dense path's own mask/einsum chain. Junk in
         # never-written or stale page offsets is masked to -1e30 before the
         # softmax, so its weight underflows to exactly 0.0 — same as the
         # dense cache's own stale rows.
         pt = jnp.maximum(page_table, 0)  # clamp -1: masked anyway
-        kv_, hd = cache["kp"].shape[2], cache["kp"].shape[3]
-        ck = new_cache["kp"][pt].reshape(b, t, kv_, hd)
-        cv = new_cache["vp"][pt].reshape(b, t, kv_, hd)
+        kv_, hd = cache["kp"].shape[1], cache["kp"].shape[3]
+
+        def dense(pool):  # [B, NP, kv, page, hd] -> [B, T, kv, hd]
+            return pool[pt].swapaxes(2, 3).reshape(b, t, kv_, hd)
+
+        ck, cv = dense(new_cache["kp"]), dense(new_cache["vp"])
         keep = (jnp.arange(t)[None] <= pos[:, None])[:, None]  # [B,1,T]
         out = _gqa_core(q, ck, cv, keep, cfg.num_heads)
     else:

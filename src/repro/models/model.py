@@ -403,7 +403,7 @@ def _scan_fill(body, x, stacked, remat):
 
 
 def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int) -> dict:
-    """Global paged KV pool, stacked over layers: [L, P, page, kv, hd].
+    """Global paged KV pool, stacked over layers: [L, P, kv, page, hd].
 
     A physical page id addresses the same page across every layer, so one
     per-slot page table serves the whole stack (the vLLM block-table

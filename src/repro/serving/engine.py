@@ -131,9 +131,10 @@ def insert_paged_cache_slot(
     ``pt_row`` [NP] maps the slot's logical blocks to physical pages of the
     global pool; -1 entries (blocks not yet allocated — growth pages, or the
     tail past the prompt bucket) drop their writes. The prefill cache is
-    dense [L, 1, T, kv, hd]; T need not fill NP pages — the tail pads with
-    zeros, which only lands in allocated pages past the prompt where decode
-    overwrites it before validity ever reaches it.
+    dense [L, 1, T, kv, hd] and the pool [L, P, kv, page, hd]; T need not
+    fill NP pages — the tail pads with zeros, which only lands in allocated
+    pages past the prompt where decode overwrites it before validity ever
+    reaches it.
     """
     npg = pt_row.shape[0]
 
@@ -141,7 +142,7 @@ def insert_paged_cache_slot(
         l, _, t, kv, hd = dense.shape
         pad = npg * page_size - t
         d = jnp.pad(dense[:, 0], [(0, 0), (0, pad), (0, 0), (0, 0)])
-        d = d.reshape(l, npg, page_size, kv, hd)
+        d = d.reshape(l, npg, page_size, kv, hd).swapaxes(2, 3)
         # -1 would WRAP to the pool's last page (negative indices resolve
         # numpy-style before mode="drop" sees them) — remap to one-past-end
         idx = jnp.where(pt_row >= 0, pt_row, pool.shape[1])
@@ -439,10 +440,11 @@ class Engine:
         one jit, all inputs device-resident (transfer-free by design)."""
         occupied = estate.inst >= 0
         decoding = occupied & (estate.gen_idx < estate.max_new)
-        logits, cache = Mdl.decode_step(
-            params, self.cfg, estate.cache, estate.cur_tok, estate.pos,
-            page_table=estate.page_table,
-        )
+        logits, cache = self.recorder.per_device(
+            lambda p, c, tok, pos, pt: Mdl.decode_step(
+                p, self.cfg, c, tok, pos, page_table=pt
+            )
+        )(params, estate.cache, estate.cur_tok, estate.pos, estate.page_table)
         nxt = self._sample(logits, estate.inst, estate.gen_idx)
         bidx = jnp.arange(self.slots)
         tgt = jnp.where(decoding, estate.gen_idx, self.max_gen)

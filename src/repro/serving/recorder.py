@@ -56,7 +56,9 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+from repro.distributed.compat import shard_map
 
 from repro import obs
 from repro.core import device_ledger as dledger
@@ -178,9 +180,8 @@ class OutcomeRecorder:
 
     ``retention`` picks the retained-outcome layout (module doc):
     ``"full"`` the dense [S, G, V] oracle, ``"topk"`` the compressed
-    (top-``topk`` values/indices, exact lse) summary; ``topk_impl``
-    forwards to ``kernels.ops.topk_lse`` ("ref"/"pallas"/"interpret",
-    None = the module default).
+    (top-``topk`` values/indices, exact lse) summary, computed by
+    ``kernels.ops.topk_lse`` (the Pallas kernel on a TPU).
     """
 
     def __init__(
@@ -199,7 +200,6 @@ class OutcomeRecorder:
         logits_dtype=jnp.float32,
         retention: str = "full",
         topk: int = 64,
-        topk_impl: Optional[str] = None,
     ):
         assert ledger in LEDGERS, ledger
         assert retention in RETENTIONS, retention
@@ -213,7 +213,6 @@ class OutcomeRecorder:
         self.topk = min(int(topk), vocab)
         if self.topk <= 0:
             raise ValueError(f"topk must be positive, got {topk}")
-        self.topk_impl = topk_impl
         self.ops: Optional[ShardedLedgerOps] = None
         self.host_history: Optional[LossHistory] = None
         if ledger == "device" and mesh is not None:
@@ -244,8 +243,8 @@ class OutcomeRecorder:
 
     def _summarize(self, logits: Array) -> tuple[Array, Array, Array]:
         """[T, V] -> (vals [T,K], idx [T,K], lse [T]) via the fused kernel."""
-        return kops.topk_lse(
-            logits.astype(F32), self.topk, impl=self.topk_impl
+        return self.per_device(lambda x: kops.topk_lse(x, self.topk))(
+            logits.astype(F32)
         )
 
     # -- state ---------------------------------------------------------------
@@ -257,10 +256,18 @@ class OutcomeRecorder:
         exactly what transfer_guard("disallow") rejects."""
         if self.ops is None:
             return tree
-        from jax.sharding import NamedSharding, PartitionSpec
-
         sh = NamedSharding(self.ops.mesh, PartitionSpec())
         return jax.tree.map(lambda x: jax.device_put(x, sh), tree)
+
+    def per_device(self, fn):
+        """``fn`` over mesh-replicated inputs, run whole on each device
+        (sharded recorders only; otherwise ``fn`` itself). The compiler
+        cannot partition a Pallas kernel on its own, and each device
+        then runs the one-device program."""
+        if self.ops is None:
+            return fn
+        return shard_map(fn, mesh=self.ops.mesh, in_specs=PartitionSpec(),
+                         out_specs=PartitionSpec())
 
     def init_state(self) -> RecorderState:
         s, g, v, k = self.slots, self.max_gen, self.vocab, self.topk

@@ -217,13 +217,14 @@ def test_decode_attn_single_valid_position():
 
 
 def _paged_case(b, hq, hkv, d, page, npg, seed=3):
-    """Random pool + per-row page tables: each row owns a random subset of
-    physical pages (shuffled — logical order != physical order), with the
-    blocks past ``pages_for(pos+1)`` unallocated (-1)."""
+    """Random head-major pool [P, Hkv, page, D] + per-row page tables: each
+    row owns a random subset of physical pages (shuffled — logical order
+    != physical order), with the blocks past ``pages_for(pos+1)``
+    unallocated (-1)."""
     ks = jax.random.split(jax.random.key(seed), 4)
     pool_pages = b * npg + 3  # spare pages nobody owns
-    kp = jax.random.normal(ks[0], (pool_pages, page, hkv, d), jnp.float32)
-    vp = jax.random.normal(ks[1], (pool_pages, page, hkv, d), jnp.float32)
+    kp = jax.random.normal(ks[0], (pool_pages, hkv, page, d), jnp.float32)
+    vp = jax.random.normal(ks[1], (pool_pages, hkv, page, d), jnp.float32)
     q = jax.random.normal(ks[2], (b, hq, d), jnp.float32)
     rng = np.random.default_rng(seed)
     perm = rng.permutation(pool_pages)
@@ -247,8 +248,8 @@ def test_paged_decode_attn_ref_equals_dense_gather(b, hq, hkv, d, page, npg):
     q, kp, vp, pt, pos = _paged_case(b, hq, hkv, d, page, npg)
     out = ref.paged_decode_attn_ref(q, kp, vp, pt, pos)
     ptc = np.maximum(np.asarray(pt), 0)
-    k = np.asarray(kp)[ptc].reshape(b, npg * page, hkv, d)
-    v = np.asarray(vp)[ptc].reshape(b, npg * page, hkv, d)
+    k = np.asarray(kp)[ptc].swapaxes(2, 3).reshape(b, npg * page, hkv, d)
+    v = np.asarray(vp)[ptc].swapaxes(2, 3).reshape(b, npg * page, hkv, d)
     valid = np.arange(npg * page)[None] <= np.asarray(pos)[:, None]
     want = ref.decode_attn_ref(q, jnp.asarray(k), jnp.asarray(v),
                                jnp.asarray(valid))
@@ -342,19 +343,23 @@ def test_ssd_decode_step_matches_scan_tail():
     np.testing.assert_allclose(np.asarray(st), np.asarray(st_full), atol=1e-4, rtol=1e-3)
 
 
-def test_ops_dispatch_modes():
+def test_ops_dispatch_modes(monkeypatch):
     logits = jax.random.normal(RNG, (8, 64))
     labels = jax.random.randint(RNG, (8,), 0, 64)
     a = ops.xent_loss(logits, labels, "ref")
     b = ops.xent_loss(logits, labels, "interpret")
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
-    assert ops.get_default_impl() == "ref"
-    ops.set_default_impl("interpret")
-    try:
-        c = ops.xent_loss(logits, labels)
-        np.testing.assert_allclose(np.asarray(c), np.asarray(a), atol=1e-5)
-    finally:
-        ops.set_default_impl("ref")
+    # the platform rule: the CPU backend defaults to the jnp oracle ...
+    assert ops.default_impl() == "ref"
+    c = ops.xent_loss(logits, labels)
+    np.testing.assert_array_equal(np.asarray(c), np.asarray(a))
+    # ... a TPU backend to the Pallas kernels; an explicit impl still wins
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.default_impl() == "pallas"
+    assert ops.resolve(None) == "pallas"
+    assert ops.resolve("interpret") == "interpret"
+    with pytest.raises(ValueError):
+        ops.resolve("mosaic")
 
 
 # ---------------------------------------------------------------------------

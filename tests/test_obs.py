@@ -146,6 +146,33 @@ def test_trace_recorder_bounded(tmp_path):
     assert doc["otherData"]["dropped_events"] == 6
 
 
+def test_ledger_spans_open_on_host_calls_only():
+    """A ledger op dispatched from host Python opens one span; the same op
+    traced inside a jit (as the fused engine and train steps call it)
+    opens none — the span would time the trace, not the run."""
+    from jax.sharding import Mesh
+
+    from repro.core import device_ledger as dledger
+    from repro.distributed.ledger import sharded_ledger_ops
+
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
+    ops = sharded_ledger_ops(mesh, LCFG, ("data",), route=True)
+    state = ops.init()
+    ids = jnp.arange(4, dtype=jnp.int32)
+    losses = jnp.ones((4,), jnp.float32)
+    telem = obs.Telemetry(trace_out="unused.json")
+    prev = obs.current()
+    obs.install(telem)
+    try:
+        state = ops.record(state, ids, losses, 1)
+        jax.jit(lambda st, i, l: ops.record(st, i, l, 2))(state, ids, losses)
+        jax.jit(lambda st, i: ops.lookup(st, i))(state, ids)
+    finally:
+        obs.install(prev)
+    assert [e["name"] for e in telem.trace.events] == ["ledger.record"]
+    assert isinstance(state, dledger.LedgerState)
+
+
 def test_rate_of_and_drift_helpers():
     assert obs.rate_of(3, 4) == 0.75
     assert obs.rate_of(3, 0) == 0.0  # empty denominator, not a crash

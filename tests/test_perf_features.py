@@ -105,10 +105,9 @@ def test_constraints_are_noops_without_rules():
 def test_int8_zero3_gather_values_and_grads():
     from repro.distributed import sharding as S
 
-    mesh_kwargs = {}
-    if hasattr(jax.sharding, "AxisType"):  # newer-jax explicit Auto axes
-        mesh_kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * 2
-    mesh = jax.make_mesh((1, 1), ("data", "model"), **mesh_kwargs)
+    mesh = jax.make_mesh(
+        (1, 1), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2
+    )
     rules = dataclasses.replace(
         S.DEFAULT_RULES, gather_params=True, int8_gather=True
     )

@@ -19,9 +19,9 @@ SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import numpy as np, jax, jax.numpy as jnp
-from jax.sharding import Mesh
 from repro import configs
 from repro.core.history import HistoryConfig, slot_for
+from repro.launch.mesh import make_elastic_mesh
 from repro.models import model as Mdl
 from repro.models.params import materialize
 from repro.serving import Engine, OutcomeRecorder
@@ -51,7 +51,9 @@ def run(mesh, route, exchange="gather", cf=1.25, **kw):
     assert eng.stats()["in_flight"] == 0, eng.stats()
     return eng, ids
 
-mesh = Mesh(np.asarray(jax.devices()).reshape(4), ("data",))
+# the CLIs' own mesh: every device on the "data" axis the ledger shards over
+mesh = make_elastic_mesh()
+assert dict(mesh.shape) == {"data": 4, "model": 1}, mesh.shape
 eng_routed, ids = run(mesh, route=True)
 assert eng_routed.recorder.ops.shards == 4
 eng_single, ids2 = run(None, route=False)
@@ -147,7 +149,7 @@ late_routed = run_late(mesh, True)
 assert int(late_routed.stats()["recorded"]) == want, late_routed.stats()
 lab = late_routed._rstate.labels
 assert isinstance(lab.sharding, NamedSharding), lab.sharding
-assert dict(lab.sharding.mesh.shape) == {"data": 4}, lab.sharding
+assert lab.sharding.mesh.shape["data"] == 4, lab.sharding
 late_single = run_late(None, False)
 sd_lr, sd_ls = (late_routed.ledger_state_dict(),
                 late_single.ledger_state_dict())
