@@ -390,7 +390,7 @@ def main(argv=None) -> int:
                 "labels": jnp.asarray(raw["labels"]),
             }
             rng, sub = jax.random.split(rng)
-            with telem.span("train.step", step=step):
+            with telem.span("train.dispatch", step=step):
                 if use_device_ledger:
                     batch["instance_id"] = jnp.asarray(
                         raw["instance_id"].astype(np.int32)

@@ -1,4 +1,4 @@
-"""repro.obs — unified, dependency-free telemetry for the recycle loop.
+"""repro.obs — unified telemetry for the recycle loop.
 
 One subsystem, three outputs, every surface (serving engine, trainer,
 benches, nightly tooling) reporting through it:
@@ -7,23 +7,29 @@ benches, nightly tooling) reporting through it:
   series. Hot paths update instruments from **already-fetched** numpy
   step metrics only (host-side accumulation): instrumentation adds zero
   device syncs, pinned by a ``transfer_guard("disallow")`` test.
-* :class:`TraceRecorder` + ``span()`` — host wall-time spans around the
-  hot paths (admission, bucketed prefill, fused decode, scoring, trainer
-  step, checkpoint save/restore, ledger exchanges), exported as Chrome
-  ``trace_event`` JSON (``--trace-out``, open in Perfetto).
+* ``span()`` — host spans around the hot paths (eviction, admission,
+  bucketed prefill, fused decode, scoring, trainer step, checkpoint
+  save/restore, ledger exchanges). Each is a
+  ``jax.profiler.TraceAnnotation``, so under a JAX profiler session it
+  lands on the device trace's clock; a :class:`TraceRecorder` also
+  exports them as Chrome ``trace_event`` JSON (``--trace-out``, open in
+  Perfetto).
 * :class:`EventLog` — structured JSONL (``--metrics-out``): periodic
   loop-health snapshots (rates + EMA drift, see :mod:`repro.obs.health`)
   and a final summary that subsumes ``Engine.stats()`` / ``--json-out``.
 
 Library code reaches telemetry through :func:`current` (a disabled
-:class:`Telemetry` by default — null instruments, null spans, ~one
-attribute call of overhead); CLIs build a real one and :func:`install` it.
+:class:`Telemetry` by default — null instruments, profiler annotations
+only, well under a microsecond a span without a profiler session); CLIs
+build a real one and :func:`install` it.
 See ``docs/observability.md`` for the metric catalog and schemas.
 """
 
 from __future__ import annotations
 
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.health import ledger_drift, rate_of
 from repro.obs.registry import (
@@ -40,8 +46,8 @@ from repro.obs.trace import NULL_SPAN, TraceRecorder, load_trace
 class Telemetry:
     """Facade bundling a registry, an optional JSONL event log, and an
     optional trace recorder. A disabled instance (``enabled=False``) hands
-    out shared null instruments/spans so call sites bind once and hot
-    loops pay (almost) nothing.
+    out shared null instruments so call sites bind once and hot loops pay
+    (almost) nothing; its spans are profiler annotations alone.
     """
 
     def __init__(
@@ -81,13 +87,12 @@ class Telemetry:
     # -- spans / events ------------------------------------------------------
 
     def span(self, name: str, cat: str = "host", **args):
+        """A context manager timing one host region: a profiler
+        annotation ``name`` with ``args`` as its stats, plus a Chrome JSON
+        event (category ``cat``) when a trace recorder is attached."""
         if self.trace is None:
-            return NULL_SPAN
+            return TraceAnnotation(name, **args)
         return self.trace.span(name, cat, **args)
-
-    def mark(self, name: str, cat: str = "host", **args) -> None:
-        if self.trace is not None:
-            self.trace.instant(name, cat, **args)
 
     def event(self, kind: str, **fields) -> None:
         if self.events is not None:
@@ -141,9 +146,9 @@ def add_cli_args(ap) -> None:
 
 def from_args(args) -> Telemetry:
     """Build AND install process-wide telemetry from the CLI flags —
-    disabled (null instruments, null spans) when neither output was
-    requested; installed either way so un-threaded call sites (checkpoint
-    manager, ledger ops) resolve consistently."""
+    disabled (null instruments, annotation-only spans) when neither
+    output was requested; installed either way so un-threaded call sites
+    (checkpoint manager, ledger ops) resolve consistently."""
     return install(
         Telemetry(
             metrics_out=args.metrics_out or None,
@@ -158,10 +163,6 @@ def span(name: str, cat: str = "host", **args):
     call sites (checkpoint manager, ledger ops) that don't thread a
     Telemetry handle."""
     return _current.span(name, cat, **args)
-
-
-def mark(name: str, cat: str = "host", **args) -> None:
-    _current.mark(name, cat, **args)
 
 
 __all__ = [
@@ -179,7 +180,6 @@ __all__ = [
     "install",
     "ledger_drift",
     "load_trace",
-    "mark",
     "rate_of",
     "read_jsonl",
     "series_key",

@@ -1,18 +1,26 @@
-"""Hot-path timing spans -> Chrome ``trace_event`` JSON (Perfetto-loadable).
+"""Hot-path timing spans, on the JAX profiler's clock and, with
+``--trace-out``, as Chrome ``trace_event`` JSON (Perfetto-loadable).
 
-``span("engine.decode_step", slot_count=8)`` times a host-side region and
-appends one complete ("ph": "X") event; ``TraceRecorder.save`` writes the
-standard ``{"traceEvents": [...]}`` envelope that chrome://tracing and
-https://ui.perfetto.dev open directly (``--trace-out``).
+Every span opens a ``jax.profiler.TraceAnnotation`` named for it, its
+keyword arguments attached as the event's stats. While a JAX profiler
+session runs (``jax.profiler.start_trace``), the span therefore lands on
+the profiler's host plane, on the same clock as the device's programs and
+operations, so a gap on the device can be read against what the host was
+doing in it. Without a session an annotation costs well under a
+microsecond to open and close.
+
+A :class:`TraceRecorder` (``--trace-out``) also keeps one complete
+("ph": "X") event per span, timed on ``time.perf_counter``;
+``TraceRecorder.save`` writes the standard ``{"traceEvents": [...]}``
+envelope that chrome://tracing and https://ui.perfetto.dev open directly.
 
 Spans measure HOST wall time at dispatch granularity: a span around a
-jitted call times enqueue + (on sync) completion, which is exactly the
-engine/trainer step latency the loop-health gauges report. Spans must
-never run inside ``jax.trace``-d code — a traced span would record
-compile-time once and nothing at run time; call sites that can be traced
-(the sharded ledger ops) guard with a tracer check before opening one.
+jitted call times enqueue + (on sync) completion. Spans must never run
+inside ``jax.trace``-d code — a traced span would record compile-time
+once and nothing at run time; call sites that can be traced (the sharded
+ledger ops) guard with a tracer check and take :data:`NULL_SPAN` instead.
 
-Stdlib-only; thread-safe appends (the checkpoint save thread emits spans).
+Thread-safe appends (the checkpoint save thread emits spans).
 """
 
 from __future__ import annotations
@@ -21,11 +29,12 @@ import json
 import os
 import threading
 import time
-from typing import Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class _NullSpan:
-    """Reusable no-op context manager for the disabled path."""
+    """Reusable no-op context manager for code being traced by JAX."""
 
     __slots__ = ()
 
@@ -40,7 +49,10 @@ NULL_SPAN = _NullSpan()
 
 
 class Span:
-    __slots__ = ("_rec", "name", "cat", "args", "_t0")
+    """A profiler annotation that also appends its Chrome JSON event to
+    a :class:`TraceRecorder` when it closes."""
+
+    __slots__ = ("_rec", "name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, rec: "TraceRecorder", name: str, cat: str, args: dict):
         self._rec = rec
@@ -49,6 +61,8 @@ class Span:
         self.args = args
 
     def __enter__(self):
+        self._ann = TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -56,6 +70,7 @@ class Span:
         self._rec._complete(
             self.name, self.cat, self._t0, time.perf_counter(), self.args
         )
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -92,26 +107,6 @@ class TraceRecorder:
 
     def span(self, name: str, cat: str = "host", **args) -> Span:
         return Span(self, name, cat, args)
-
-    def instant(self, name: str, cat: str = "host", **args) -> None:
-        """A zero-duration marker (ph "i"): admissions, evictions,
-        deliveries — the discrete control-plane events between spans."""
-        if len(self.events) >= self.max_events:
-            self.dropped += 1
-            return
-        ev = {
-            "ph": "i",
-            "s": "t",
-            "name": name,
-            "cat": cat,
-            "ts": (time.perf_counter() - self._epoch) * 1e6,
-            "pid": os.getpid(),
-            "tid": threading.get_ident() & 0x7FFFFFFF,
-        }
-        if args:
-            ev["args"] = args
-        with self._lock:
-            self.events.append(ev)
 
     def save(self, path: str) -> None:
         with self._lock, open(path, "w", encoding="utf-8") as f:

@@ -70,6 +70,7 @@ class Request:
     instance_id: int
     labels: Optional[np.ndarray] = None
     expect_labels: bool = False
+    submit_t: float = 0.0  # time.perf_counter() at submit()
 
 
 @jax.tree_util.register_pytree_node_class
@@ -322,10 +323,7 @@ class Engine:
         # params go in as an ARGUMENT (closing over them would bake the
         # weights into the jaxpr as constants)
         self._decode = jax.jit(self._fused_step, donate_argnums=(1, 2))
-        self._deliver = jax.jit(
-            lambda rs, slot, row: self.recorder.deliver(rs, slot, row),
-            donate_argnums=(0,),
-        )
+        self._deliver = jax.jit(self._deliver_fn, donate_argnums=(0,))
         # paged-mode host->device page-table maintenance (outside the
         # transfer guard, like admission): scatter freshly grown pages /
         # clear evicted rows, both at fixed [slots] shape with -1 padding
@@ -391,16 +389,24 @@ class Engine:
             step=jnp.zeros((), I32),
         )
 
+    # every program is a named method, so its XLA module in a profile
+    # (jit__prefill_fn, jit__insert_fn, jit__fused_step, ...) reads apart
+    # from the others
+
     def _prefill(self, padded_len: int):
         fn = self._prefill_jits.get(padded_len)
         if fn is None:
-            fn = jax.jit(
-                lambda p, t, lp: Mdl.prefill(
-                    p, self.cfg, t, max_seq=self.max_seq, last_pos=lp
-                )
-            )
+            fn = jax.jit(self._prefill_fn)
             self._prefill_jits[padded_len] = fn
         return fn
+
+    def _prefill_fn(self, params, toks, last_pos):
+        return Mdl.prefill(
+            params, self.cfg, toks, max_seq=self.max_seq, last_pos=last_pos
+        )
+
+    def _deliver_fn(self, rstate, slot, row):
+        return self.recorder.deliver(rstate, slot, row)
 
     def _insert_fn(
         self, estate, rstate, new_cache, logits0, slot, inst, plen, max_new,
@@ -575,7 +581,7 @@ class Engine:
         self._queue.append(
             Request(prompt, max_new, int(instance_id),
                     None if labels is None else np.asarray(labels, np.int64),
-                    bool(expect_labels))
+                    bool(expect_labels), time.perf_counter())
         )
         return int(instance_id)
 
@@ -630,7 +636,8 @@ class Engine:
 
     def _admit(self, req: Request) -> None:
         with self.telemetry.span(
-            "engine.admit", inst=req.instance_id, prompt=int(req.prompt.size)
+            "engine.admit", inst=req.instance_id, prompt=int(req.prompt.size),
+            waited_ms=(time.perf_counter() - req.submit_t) * 1e3,
         ):
             self._admit_inner(req)
         self._c_admitted.inc()
@@ -652,7 +659,9 @@ class Engine:
         toks = np.full((1, p), self.pad_token, np.int32)
         toks[0, : req.prompt.size] = req.prompt
         lp = np.asarray([req.prompt.size - 1], np.int32)
-        with self.telemetry.span("engine.prefill", padded_len=p):
+        with self.telemetry.span(
+            "engine.prefill", padded_len=p, prompt=int(req.prompt.size)
+        ):
             logits0, new_cache = self._prefill(p)(
                 self.params, jnp.asarray(toks), jnp.asarray(lp)
             )
@@ -667,11 +676,12 @@ class Engine:
             cut = int((req.labels[req.max_new:] >= 0).sum())
             self.missed_outcomes += cut
             self._c_missed.inc(cut)
-        self._estate, self._rstate = self._insert(
-            self._estate, self._rstate, new_cache, logits0,
-            slot, req.instance_id, req.prompt.size, req.max_new,
-            jnp.asarray(row.astype(np.int32)), pt_row,
-        )
+        with self.telemetry.span("engine.insert"):
+            self._estate, self._rstate = self._insert(
+                self._estate, self._rstate, new_cache, logits0,
+                slot, req.instance_id, req.prompt.size, req.max_new,
+                jnp.asarray(row.astype(np.int32)), pt_row,
+            )
         self._slot_of[req.instance_id] = slot
         self._max_new_of[req.instance_id] = req.max_new
         self._await_labels[req.instance_id] = req.expect_labels
@@ -693,6 +703,12 @@ class Engine:
                 done.append((inst, slot, int(m["gen_idx"][slot])))
         if not done:
             return
+        with self.telemetry.span(
+            "engine.evict", insts=[inst for inst, _, _ in done]
+        ):
+            self._evict(done)
+
+    def _evict(self, done: list[tuple[int, int, int]]) -> None:
         # ONE batched fetch of every evicting slot's token rows (was one
         # device_get per slot); the per-slot :gen cut happens on host
         with self.telemetry.span("engine.evict_fetch", n=len(done)):
@@ -725,9 +741,10 @@ class Engine:
             # (a -1 pad would wrap to the last slot and wipe its row)
             arr = np.full((self.slots,), self.slots, np.int32)
             arr[: len(cleared)] = cleared
-            self._estate = self._clear_jit(
-                self._estate, self.recorder.replicate(jnp.asarray(arr))
-            )
+            with self.telemetry.span("engine.clear"):
+                self._estate = self._clear_jit(
+                    self._estate, self.recorder.replicate(jnp.asarray(arr))
+                )
 
     def in_flight_ids(self) -> tuple[int, ...]:
         """Instance ids currently resident in a slot (admission order)."""
@@ -743,7 +760,13 @@ class Engine:
         )
 
     def step(self) -> Optional[dict]:
-        """One engine tick: evict -> admit -> fused decode+score+record."""
+        """One engine tick: evict -> admit -> fused decode+score+record.
+
+        Each phase opens its span (``engine.evict``, ``engine.admit``,
+        ``engine.grow_pages``, ``engine.decode_step``,
+        ``engine.fetch_metrics``, ``engine.account``): under a JAX profiler
+        session they lie on the device trace's clock."""
+        t0 = time.perf_counter()
         self._evict_done()
         while self._free:
             # a request whose instance id is already resident must wait for
@@ -772,8 +795,8 @@ class Engine:
         if not self._slot_of:
             return None
         if self.pool is not None:
-            self._grow_pages()
-        t0 = time.perf_counter()
+            with self.telemetry.span("engine.grow_pages"):
+                self._grow_pages()
         with self.telemetry.span(
             "engine.decode_step", occupied=len(self._slot_of)
         ):
@@ -788,6 +811,14 @@ class Engine:
         self._estate, self._rstate, metrics = out
         with self.telemetry.span("engine.fetch_metrics"):
             metrics = jax.device_get(metrics)
+        with self.telemetry.span("engine.account"):
+            self._account(metrics, t0)
+        return metrics
+
+    def _account(self, metrics: dict, t0: float) -> None:
+        """Host work on one step's fetched metrics: the host or shadow
+        ledger record, the counters, the ``pos`` mirror; ``t0`` is when
+        the step began."""
         self._fresh_labels.clear()  # this step's `pending` saw every label
         if self.recorder.host_history is not None:
             self.recorder.record_host(
@@ -819,7 +850,6 @@ class Engine:
             # on): advances exactly where the step decoded
             self._pos_host += np.asarray(metrics["decoding"], bool)
         self._obs_on_step(metrics, (time.perf_counter() - t0) * 1e3)
-        return metrics
 
     def _obs_on_step(
         self, metrics: dict, dt_ms: Optional[float] = None
