@@ -12,6 +12,10 @@ blocks in VMEM scratch. Query heads of one KV group (G = Hq/Hkv) ride the
 sublane dim together. Validity (cache occupancy, sliding windows, rolling
 slots) arrives as a precomputed [B, T] int8 mask, so one kernel serves all
 cache layouts.
+
+The paged kernel (``paged_decode_attn``) reads K/V through a page table
+instead: grid (B, page blocks), each step gathering the attended pages of
+one slot for every kv head by manual DMA.
 """
 
 from __future__ import annotations
@@ -65,46 +69,133 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_s, s_s, acc_s):
         )
 
 
-def _paged_decode_kernel(
-    pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_s, s_s, acc_s
-):
-    b = pl.program_id(0)
-    pi = pl.program_id(2)
-    npg = pl.num_programs(2)
-    g, d = q_ref.shape
-    page = k_ref.shape[0]
+# K bytes (all kv heads) one grid step of the paged kernel gathers: large
+# enough to amortise a grid step's fixed cost, small enough that K and V,
+# double-buffered, take 4 MiB of the scoped VMEM
+_PAGED_BLOCK_BYTES = 1 << 20
 
-    @pl.when(pi == 0)
+
+def _pages_per_block(hkv: int, page: int, d: int, itemsize: int,
+                     npg: int) -> int:
+    """Pages one grid step gathers, from the bytes of one physical page of
+    all kv heads: 32 pages of 16 at Qwen3's 8 x 128 bf16 heads, 8 at an
+    MHA's 32 heads."""
+    return max(1, min(npg, _PAGED_BLOCK_BYTES // (hkv * page * d * itemsize)))
+
+
+def _paged_decode_kernel(
+    pt_ref, pos_ref, q_ref, kp_hbm, vp_hbm, o_ref,
+    kbuf, vbuf, sems, cur_ref, m_s, s_s, acc_s, *, npg, ppb,
+):
+    b, i = pl.program_id(0), pl.program_id(1)
+    nslots, nblk = pl.num_programs(0), pl.num_programs(1)
+    _, _, hkv, page, d = kbuf.shape
+    bt = ppb * page
+
+    def attended(bb, ii):
+        """Pages of block (bb, ii) the slot attends: logical pages up to
+        pos // page; none when the slot holds no first page (a free
+        slot)."""
+        last = jnp.minimum(pos_ref[bb] // page, npg - 1)
+        n = jnp.clip(last + 1 - ii * ppb, 0, ppb)
+        return jnp.where(pt_ref[bb * npg] >= 0, n, 0)
+
+    def each_copy(bb, ii, slot, op):
+        """``op`` on the DMA of every attended, allocated page of block
+        (bb, ii): one DMA per page moves all kv heads ([Hkv, page, D] is
+        contiguous in the head-major pool)."""
+
+        def body(j, carry):
+            phys = pt_ref[bb * npg + ii * ppb + j]
+
+            @pl.when(phys >= 0)
+            def _():
+                for n, (pool, buf) in enumerate(((kp_hbm, kbuf),
+                                                 (vp_hbm, vbuf))):
+                    op(pltpu.make_async_copy(
+                        pool.at[phys], buf.at[slot, j], sems.at[n, slot]))
+
+            return carry
+
+        jax.lax.fori_loop(0, attended(bb, ii), body, 0)
+
+    def first_slot_from(bb):
+        """The first slot >= bb that attends anything (nslots if none)."""
+        return jax.lax.while_loop(
+            lambda c: (c < nslots)
+            & (attended(jnp.minimum(c, nslots - 1), 0) == 0),
+            lambda c: c + 1,
+            bb,
+        )
+
+    @pl.when((b == 0) & (i == 0))
+    def _start_first():
+        first = first_slot_from(0)
+        cur_ref[0] = 0
+
+        @pl.when(first < nslots)
+        def _():
+            each_copy(first, 0, 0, lambda c: c.start())
+
+    @pl.when(i == 0)
     def _init():
         m_s[...] = jnp.full_like(m_s, NEG_INF)
         s_s[...] = jnp.zeros_like(s_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    q = q_ref[...].astype(F32)  # [G, D]
-    k = k_ref[...].astype(F32)  # [page, D] — the gathered physical page
-    v = v_ref[...].astype(F32)
-    scores = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=F32
-    ) * (d**-0.5)  # [G, page]
-    # validity is computed in-kernel from (logical position, pos): page pi
-    # covers logical positions [pi*page, (pi+1)*page); position pos itself
-    # (the token just written) is attended. An unallocated table entry
-    # (-1, DMA'd clamped to page 0) is masked wholesale.
-    t = pi * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
-    ok = (t <= pos_ref[b]) & (pt_ref[b, pi] >= 0)
-    scores = jnp.where(ok, scores, NEG_INF)
+    @pl.when(attended(b, i) > 0)
+    def _block():
+        # this block's pages are in flight into buffer `cur`; start the
+        # next block that attends anything (this slot's, else the next
+        # such slot's first) into the other buffer before computing
+        cur = cur_ref[0]
+        more = (i + 1 < nblk) & (
+            attended(b, jnp.minimum(i + 1, nblk - 1)) > 0
+        )
+        nb, ni = jax.lax.cond(
+            more,
+            lambda: (b, i + 1),
+            lambda: (first_slot_from(b + 1), jnp.int32(0)),
+        )
 
-    m_prev, s_prev = m_s[...], s_s[...]  # [G, 1]
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-    p = jnp.exp(scores - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    s_s[...] = s_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-    acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=F32
-    )
-    m_s[...] = m_new
+        @pl.when(nb < nslots)
+        def _():
+            each_copy(nb, ni, 1 - cur, lambda c: c.start())
+            cur_ref[0] = 1 - cur
 
-    @pl.when(pi == npg - 1)
+        each_copy(b, i, cur, lambda c: c.wait())
+
+        # position pos itself (the token just written) is attended; pages
+        # of the buffer not gathered this step hold stale data, masked out
+        # of the scores and zeroed out of V
+        t0 = i * bt + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+        ok = t0 <= pos_ref[b]  # [1, bt]
+        ok_v = i * bt + jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0)
+        ok_v = ok_v <= pos_ref[b]  # [bt, 1]
+        dt = jnp.promote_types(q_ref.dtype, kbuf.dtype)
+        for h in range(hkv):
+            # bf16 x bf16 products are exact in f32: reading K in the pool's
+            # dtype loses nothing against an f32 upcast
+            q = q_ref[h].astype(dt)  # [G, D]
+            k = kbuf[cur, :, h].reshape(bt, d).astype(dt)  # [bt, D]
+            v = vbuf[cur, :, h].reshape(bt, d).astype(F32)
+            v = jnp.where(ok_v, v, 0.0)
+            scores = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), preferred_element_type=F32
+            ) * (d**-0.5)  # [G, bt]
+            scores = jnp.where(ok, scores, NEG_INF)
+            m_prev, s_prev = m_s[h], s_s[h]  # [G, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1,
+                                                keepdims=True))
+            p = jnp.exp(scores - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            s_s[h] = s_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc_s[h] = acc_s[h] * corr + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())), preferred_element_type=F32
+            )
+            m_s[h] = m_new
+
+    @pl.when(i == nblk - 1)
     def _emit():
         o_ref[...] = (acc_s[...] / jnp.maximum(s_s[...], 1e-30)).astype(
             o_ref.dtype
@@ -121,55 +212,60 @@ def paged_decode_attn(
     *,
     interpret: bool = False,
 ) -> jax.Array:
-    """Paged flash decode attention: the dense kernel's grid extended to
-    gather K/V blocks *through the page table*. The table and positions
-    ride in as scalar-prefetch operands (``PrefetchScalarGridSpec``), so
-    the K/V BlockSpec index maps can address physical pages — each grid
-    step DMAs exactly one page; no [B, T, ...] dense gather ever
-    materializes. Grid (B, Hkv, NP), pages minor, online-softmax state in
-    VMEM scratch exactly like :func:`decode_attn`. The pool is head-major
-    so each K/V block (one page of one kv head) spans the array's last two
-    dims whole, the block shape the TPU lowering accepts at any page size
-    and head dim."""
+    """Paged flash decode attention over only the pages a slot attends.
+
+    Grid (B, ceil(NP / ppb)), pages minor. A grid step gathers a block of
+    up to ``ppb`` logical pages of one slot (:func:`_pages_per_block`,
+    ~1 MiB of K) from the pool in HBM with one DMA per page for all kv
+    heads, and loops over the kv heads: the G query heads of a group
+    against that head's [ppb * page, D] keys, with the online-softmax
+    state of every head in VMEM scratch. The table and positions ride in
+    as scalar-prefetch operands. Only logical pages 0 .. pos // page with
+    a table entry >= 0 are copied; a block past a slot's depth, and every
+    block of a slot with no first page, does no work, and the next
+    block that attends anything is prefetched while the current one
+    computes (the pattern of JAX's TPU ``paged_attention`` kernel).
+    Scores, max, sum and accumulator are f32; a slot that attends nothing
+    reads zeros. The table is a prefix of allocated pages up to pos //
+    page, as the engine keeps it."""
     b, hq, d = q.shape
-    p_, hkv, page, _ = kp.shape
+    _, hkv, page, _ = kp.shape
     npg = page_table.shape[1]
     g = hq // hkv
+    ppb = _pages_per_block(hkv, page, d, kp.dtype.itemsize, npg)
     qr = q.reshape(b, hkv, g, d)
-    pt = jnp.asarray(page_table, jnp.int32)
+    pt = jnp.asarray(page_table, jnp.int32).reshape(-1)  # 1-D in SMEM
     posr = jnp.asarray(pos, jnp.int32)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, npg),
+        grid=(b, pl.cdiv(npg, ppb)),
         in_specs=[
-            pl.BlockSpec(
-                (None, None, g, d), lambda i, j, pi, pt, ps: (i, j, 0, 0)
-            ),
-            # physical page via the prefetched table; -1 clamps to page 0
-            # for the DMA and the kernel masks the whole block
-            pl.BlockSpec(
-                (None, None, page, d),
-                lambda i, j, pi, pt, ps: (jnp.maximum(pt[i, pi], 0), j, 0, 0),
-            ),
-            pl.BlockSpec(
-                (None, None, page, d),
-                lambda i, j, pi, pt, ps: (jnp.maximum(pt[i, pi], 0), j, 0, 0),
-            ),
+            pl.BlockSpec((None, hkv, g, d), lambda i, j, pt, ps: (i, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec(
-            (None, None, g, d), lambda i, j, pi, pt, ps: (i, j, 0, 0)
+            (None, hkv, g, d), lambda i, j, pt, ps: (i, 0, 0, 0)
         ),
         scratch_shapes=[
-            pltpu.VMEM((g, 1), F32),
-            pltpu.VMEM((g, 1), F32),
-            pltpu.VMEM((g, d), F32),
+            pltpu.VMEM((2, ppb, hkv, page, d), kp.dtype),
+            pltpu.VMEM((2, ppb, hkv, page, d), vp.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),  # (K | V, buffer)
+            pltpu.SMEM((1,), jnp.int32),  # the buffer in flight for this step
+            pltpu.VMEM((hkv, g, 1), F32),
+            pltpu.VMEM((hkv, g, 1), F32),
+            pltpu.VMEM((hkv, g, d), F32),
         ],
     )
     out = pl.pallas_call(
-        _paged_decode_kernel,
+        functools.partial(_paged_decode_kernel, npg=npg, ppb=ppb),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
+        # the prefetch chain crosses slots: both axes run in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")
+        ),
         interpret=interpret,
     )(pt, posr, qr, kp, vp)
     return out.reshape(b, hq, d)

@@ -797,9 +797,12 @@ class Engine:
         if self.pool is not None:
             with self.telemetry.span("engine.grow_pages"):
                 self._grow_pages()
-        with self.telemetry.span(
-            "engine.decode_step", occupied=len(self._slot_of)
-        ):
+        step_args = {"occupied": len(self._slot_of)}
+        if self.pool is not None:  # the pages the paged kernel walks
+            step_args["pages"] = sum(
+                len(p) for p in self._slot_pages.values()
+            )
+        with self.telemetry.span("engine.decode_step", **step_args):
             if self.guard_transfers and self._warm:
                 with jax.transfer_guard("disallow"):
                     out = self._decode(

@@ -65,14 +65,27 @@ def test_topk_lse_compiles_at_full_vocab(one_chip, rows):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("page", [16, 128])
-def test_paged_decode_attn_compiles_at_qwen3_widths(one_chip, page):
-    b, ctx = 16, 4096
-    npg = ctx // page
-    pool = (b * npg, QWEN.num_kv_heads, page, QWEN.head_dim)
+@pytest.mark.parametrize(
+    "b,npg,pool_pages,hq,hkv,page",
+    [
+        pytest.param(16, 256, 16 * 256, QWEN.num_heads, QWEN.num_kv_heads,
+                     16, id="16"),
+        pytest.param(16, 32, 16 * 32, QWEN.num_heads, QWEN.num_kv_heads,
+                     128, id="128"),
+        # the serve cell: 64 slots of 1,024 positions, a 4,096-page pool
+        pytest.param(64, 64, 4096, QWEN.num_heads, QWEN.num_kv_heads, 16,
+                     id="serve-cell"),
+        # MHA (deepseek-7b's 32 kv heads): the block rule must fit VMEM
+        pytest.param(16, 64, 16 * 64, 32, 32, 16, id="mha"),
+    ],
+)
+def test_paged_decode_attn_compiles_at_qwen3_widths(one_chip, b, npg,
+                                                    pool_pages, hq, hkv,
+                                                    page):
+    pool = (pool_pages, hkv, page, QWEN.head_dim)
     text = _compiled_text(
         DA_mod.paged_decode_attn, one_chip,
-        ((b, QWEN.num_heads, QWEN.head_dim), jnp.bfloat16),
+        ((b, hq, QWEN.head_dim), jnp.bfloat16),
         (pool, jnp.bfloat16),
         (pool, jnp.bfloat16),
         ((b, npg), jnp.int32),

@@ -62,11 +62,19 @@ def drive(engine, seed):
 
 @pytest.fixture(scope="module")
 def profiled(params, tmp_path_factory):
-    """(program spans in start order, the ids served) of one profiled
-    pass; a first pass compiles every program outside the profile."""
+    """(program spans in start order, the ids served, the pages occupied
+    slots held at each decode dispatch) of one profiled pass; a first pass
+    compiles every program outside the profile."""
     assert obs.current().enabled is False
     engine = make_engine(params)
     drive(engine, 0)
+    held, decode = [], engine._decode
+
+    def counted_decode(*args):
+        held.append(sum(len(engine._slot_pages[s])
+                        for s in engine._slot_of.values()))
+        return decode(*args)
+    engine._decode = counted_decode
     out = str(tmp_path_factory.mktemp("profile"))
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
@@ -84,7 +92,7 @@ def profiled(params, tmp_path_factory):
                     (e.start_ns, e.start_ns + e.duration_ns, e.name,
                      dict(e.stats))
                     for e in line.events if e.name.startswith("engine."))
-    return sorted(spans), ids
+    return sorted(spans), ids, held
 
 
 def inside(child, parent):
@@ -92,7 +100,7 @@ def inside(child, parent):
 
 
 def test_spans_nest_under_their_phase(profiled):
-    spans, _ = profiled
+    spans, *_ = profiled
     names = {s[2] for s in spans}
     assert names >= {
         "engine.evict", "engine.evict_fetch", "engine.clear",
@@ -117,7 +125,7 @@ def test_step_phases_run_in_order(profiled):
     """Outside label delivery (the caller's, between steps), the top-level
     spans read: eviction, admissions, page growth, the decode dispatch,
     the blocking fetch of its metrics, the host accounting."""
-    spans, _ = profiled
+    spans, *_ = profiled
     top = [s[2][len("engine."):] for s in spans
            if s[2] != "engine.deliver"
            and not any(p is not s and inside(s, p) for p in spans)]
@@ -128,7 +136,7 @@ def test_step_phases_run_in_order(profiled):
 
 
 def test_spans_carry_the_request(profiled):
-    spans, ids = profiled
+    spans, ids, _ = profiled
     admits = [s[3] for s in spans if s[2] == "engine.admit"]
     assert [a["inst"] for a in admits] == ids
     assert [a["prompt"] for a in admits] == [n for n, _ in SCHEDULE]
@@ -141,6 +149,17 @@ def test_spans_carry_the_request(profiled):
     assert sorted(evicted) == sorted(ids)
     delivered = [s[3]["inst"] for s in spans if s[2] == "engine.deliver"]
     assert sorted(delivered) == sorted(ids)
+
+
+def test_decode_step_carries_the_pages_held(profiled):
+    """``engine.decode_step`` carries ``pages``, the pages held by the
+    occupied slots at the dispatch (what the paged kernel walks), beside
+    ``occupied``."""
+    spans, _, held = profiled
+    steps = [s[3] for s in spans if s[2] == "engine.decode_step"]
+    assert [int(s["pages"]) for s in steps] == held
+    assert len(set(held)) > 1 and min(held) > 0, held
+    assert all(int(s["occupied"]) >= 1 for s in steps)
 
 
 def _module_name(jitted, args) -> str:

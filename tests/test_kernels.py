@@ -216,11 +216,13 @@ def test_decode_attn_single_valid_position():
 # ---------------------------------------------------------------------------
 
 
-def _paged_case(b, hq, hkv, d, page, npg, seed=3):
+def _paged_case(b, hq, hkv, d, page, npg, seed=3, dtype=jnp.float32,
+                extra=0):
     """Random head-major pool [P, Hkv, page, D] + per-row page tables: each
     row owns a random subset of physical pages (shuffled — logical order
-    != physical order), with the blocks past ``pages_for(pos+1)``
-    unallocated (-1)."""
+    != physical order), with the blocks past ``pages_for(pos+1) + extra``
+    unallocated (-1); ``extra`` > 0 allocates pages past the depth, as a
+    bucket-padded prompt does."""
     ks = jax.random.split(jax.random.key(seed), 4)
     pool_pages = b * npg + 3  # spare pages nobody owns
     kp = jax.random.normal(ks[0], (pool_pages, hkv, page, d), jnp.float32)
@@ -232,10 +234,11 @@ def _paged_case(b, hq, hkv, d, page, npg, seed=3):
     pt = np.full((b, npg), -1, np.int32)
     used = 0
     for i in range(b):
-        n_alloc = int(pos[i]) // page + 1
+        n_alloc = min(int(pos[i]) // page + 1 + extra, npg)
         pt[i, :n_alloc] = perm[used : used + n_alloc]
         used += n_alloc
-    return q, kp, vp, jnp.asarray(pt), jnp.asarray(pos)
+    return (q.astype(dtype), kp.astype(dtype), vp.astype(dtype),
+            jnp.asarray(pt), jnp.asarray(pos))
 
 
 @pytest.mark.parametrize(
@@ -256,16 +259,57 @@ def test_paged_decode_attn_ref_equals_dense_gather(b, hq, hkv, d, page, npg):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
 
 
+def _bf16_ulp(x):
+    """One bf16 ulp at |x| (8 significant bits)."""
+    _, e = np.frexp(np.abs(x))
+    return np.ldexp(1.0, e - 8)
+
+
 @pytest.mark.parametrize(
-    "b,hq,hkv,d,page,npg", [(2, 8, 2, 32, 16, 4), (3, 4, 4, 64, 8, 5)]
+    "b,hq,hkv,d,page,npg,dtype,extra",
+    [
+        pytest.param(2, 8, 2, 32, 16, 4, jnp.float32, 0, id="2-8-2-32-16-4"),
+        pytest.param(3, 4, 4, 64, 8, 5, jnp.float32, 0, id="3-4-4-64-8-5"),
+        pytest.param(2, 8, 8, 32, 16, 6, jnp.float32, 0, id="mha"),
+        pytest.param(3, 8, 1, 64, 16, 5, jnp.float32, 0, id="mqa"),
+        # f32, 4 x 32 x 128: 64 KiB a page, 16 pages a block, 20 pages
+        pytest.param(3, 8, 4, 128, 32, 20, jnp.float32, 0,
+                     id="np-not-a-multiple-of-the-block"),
+        pytest.param(3, 8, 2, 128, 16, 6, jnp.bfloat16, 0, id="bf16"),
+        pytest.param(3, 8, 2, 32, 8, 6, jnp.float32, 2,
+                     id="pages-allocated-past-pos"),
+    ],
 )
-def test_paged_decode_attn_kernel_matches_ref(b, hq, hkv, d, page, npg):
-    q, kp, vp, pt, pos = _paged_case(b, hq, hkv, d, page, npg)
+def test_paged_decode_attn_kernel_matches_ref(b, hq, hkv, d, page, npg,
+                                              dtype, extra):
+    q, kp, vp, pt, pos = _paged_case(b, hq, hkv, d, page, npg, dtype=dtype,
+                                     extra=extra)
+    ppb = DA_mod._pages_per_block(hkv, page, d, kp.dtype.itemsize, npg)
+    if npg % ppb:  # the partial last block is attended by some row
+        assert int(pos.max()) >= (npg // ppb) * ppb * page
     out = DA_mod.paged_decode_attn(q, kp, vp, pt, pos, interpret=True)
     want = ref.paged_decode_attn_ref(q, kp, vp, pt, pos)
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(want), atol=2e-6
-    )
+    assert out.dtype == want.dtype == dtype
+    out = np.asarray(out, np.float32)
+    want = np.asarray(want, np.float32)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(out, want, atol=2e-6)
+    else:  # f32 inside both: only the final cast to bf16 may differ
+        np.testing.assert_array_less(
+            np.abs(out - want), _bf16_ulp(np.maximum(np.abs(out),
+                                                     np.abs(want))) * 1.001)
+
+
+def test_paged_decode_attn_slot_without_pages():
+    """A free slot (table row all -1) comes out finite, and the other rows
+    still equal the ref."""
+    q, kp, vp, pt, pos = _paged_case(3, 8, 2, 32, 8, 6)
+    pt = pt.at[1].set(-1)
+    out = np.asarray(DA_mod.paged_decode_attn(q, kp, vp, pt, pos,
+                                              interpret=True))
+    want = np.asarray(ref.paged_decode_attn_ref(q, kp, vp, pt, pos))
+    assert np.isfinite(out[1]).all()
+    np.testing.assert_allclose(out[[0, 2]], want[[0, 2]], atol=2e-6)
 
 
 def test_paged_decode_attn_ops_dispatch():
