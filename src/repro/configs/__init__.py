@@ -20,6 +20,7 @@ ARCHS = (
     "musicgen_medium",
     "mamba2_370m",
     "deepseek_v2_236b",
+    "deepseek_v2_lite",
     "mixtral_8x22b",
     "pixtral_12b",
 )
@@ -35,6 +36,7 @@ _ALIASES.update(
         "musicgen-medium": "musicgen_medium",
         "mamba2-370m": "mamba2_370m",
         "deepseek-v2-236b": "deepseek_v2_236b",
+        "deepseek-v2-lite": "deepseek_v2_lite",
         "mixtral-8x22b": "mixtral_8x22b",
         "pixtral-12b": "pixtral_12b",
     }

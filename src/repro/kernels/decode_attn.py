@@ -15,7 +15,10 @@ cache layouts.
 
 The paged kernel (``paged_decode_attn``) reads K/V through a page table
 instead: grid (B, page blocks), each step gathering the attended pages of
-one slot for every kv head by manual DMA.
+one slot for every kv head by manual DMA. The paged latent kernel
+(``paged_latent_attn``, DeepSeek-V2's absorbed MLA) shares that block
+gather (:func:`_paged_blocks`): every query head of a slot attends one
+latent stream whose first columns are also the values.
 """
 
 from __future__ import annotations
@@ -83,14 +86,17 @@ def _pages_per_block(hkv: int, page: int, d: int, itemsize: int,
     return max(1, min(npg, _PAGED_BLOCK_BYTES // (hkv * page * d * itemsize)))
 
 
-def _paged_decode_kernel(
-    pt_ref, pos_ref, q_ref, kp_hbm, vp_hbm, o_ref,
-    kbuf, vbuf, sems, cur_ref, m_s, s_s, acc_s, *, npg, ppb,
-):
+def _paged_blocks(pt_ref, pos_ref, copies, sems, cur_ref, init, compute, *,
+                  npg, ppb, page):
+    """The block gather of the paged kernels, on grid (slots, page blocks)
+    with pages minor. ``copies`` pairs each pool in HBM ([P, ...], a page
+    per leading index) with its VMEM buffer [2, ppb, ...]; a grid step
+    gathers the attended pages of its block of one slot into buffer
+    ``cur`` (one DMA per page and pool) while the next block that attends
+    anything is in flight into the other one, then runs ``compute(cur)``.
+    ``init`` runs at a slot's first block."""
     b, i = pl.program_id(0), pl.program_id(1)
     nslots, nblk = pl.num_programs(0), pl.num_programs(1)
-    _, _, hkv, page, d = kbuf.shape
-    bt = ppb * page
 
     def attended(bb, ii):
         """Pages of block (bb, ii) the slot attends: logical pages up to
@@ -102,16 +108,15 @@ def _paged_decode_kernel(
 
     def each_copy(bb, ii, slot, op):
         """``op`` on the DMA of every attended, allocated page of block
-        (bb, ii): one DMA per page moves all kv heads ([Hkv, page, D] is
-        contiguous in the head-major pool)."""
+        (bb, ii), one per pool (a page of the head-major K pool holds all
+        kv heads contiguously, [Hkv, page, D])."""
 
         def body(j, carry):
             phys = pt_ref[bb * npg + ii * ppb + j]
 
             @pl.when(phys >= 0)
             def _():
-                for n, (pool, buf) in enumerate(((kp_hbm, kbuf),
-                                                 (vp_hbm, vbuf))):
+                for n, (pool, buf) in enumerate(copies):
                     op(pltpu.make_async_copy(
                         pool.at[phys], buf.at[slot, j], sems.at[n, slot]))
 
@@ -137,11 +142,7 @@ def _paged_decode_kernel(
         def _():
             each_copy(first, 0, 0, lambda c: c.start())
 
-    @pl.when(i == 0)
-    def _init():
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        s_s[...] = jnp.zeros_like(s_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
+    pl.when(i == 0)(init)
 
     @pl.when(attended(b, i) > 0)
     def _block():
@@ -164,7 +165,25 @@ def _paged_decode_kernel(
             cur_ref[0] = 1 - cur
 
         each_copy(b, i, cur, lambda c: c.wait())
+        compute(cur)
 
+
+def _init_softmax(m_s, s_s, acc_s):
+    m_s[...] = jnp.full_like(m_s, NEG_INF)
+    s_s[...] = jnp.zeros_like(s_s)
+    acc_s[...] = jnp.zeros_like(acc_s)
+
+
+def _paged_decode_kernel(
+    pt_ref, pos_ref, q_ref, kp_hbm, vp_hbm, o_ref,
+    kbuf, vbuf, sems, cur_ref, m_s, s_s, acc_s, *, npg, ppb,
+):
+    b, i = pl.program_id(0), pl.program_id(1)
+    nblk = pl.num_programs(1)
+    _, _, hkv, page, d = kbuf.shape
+    bt = ppb * page
+
+    def compute(cur):
         # position pos itself (the token just written) is attended; pages
         # of the buffer not gathered this step hold stale data, masked out
         # of the scores and zeroed out of V
@@ -194,6 +213,10 @@ def _paged_decode_kernel(
                 p, v, (((1,), (0,)), ((), ())), preferred_element_type=F32
             )
             m_s[h] = m_new
+
+    _paged_blocks(pt_ref, pos_ref, ((kp_hbm, kbuf), (vp_hbm, vbuf)), sems,
+                  cur_ref, lambda: _init_softmax(m_s, s_s, acc_s), compute,
+                  npg=npg, ppb=ppb, page=page)
 
     @pl.when(i == nblk - 1)
     def _emit():
@@ -269,6 +292,112 @@ def paged_decode_attn(
         interpret=interpret,
     )(pt, posr, qr, kp, vp)
     return out.reshape(b, hq, d)
+
+
+def _paged_latent_kernel(
+    pt_ref, pos_ref, q_ref, lat_hbm, o_ref,
+    buf, sems, cur_ref, m_s, s_s, acc_s, *, npg, ppb, scale,
+):
+    b, i = pl.program_id(0), pl.program_id(1)
+    nblk = pl.num_programs(1)
+    _, _, page, w = buf.shape
+    bt = ppb * page
+    vd = acc_s.shape[-1]
+
+    def compute(cur):
+        # as in _paged_decode_kernel: position pos is attended, pages not
+        # gathered this step are masked out of the scores and zeroed out
+        # of V
+        t0 = i * bt + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+        ok = t0 <= pos_ref[b]  # [1, bt]
+        ok_v = i * bt + jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0)
+        ok_v = ok_v <= pos_ref[b]  # [bt, 1]
+        lat = buf[cur].reshape(bt, w)  # [bt, W]
+        # bf16 x bf16 products are exact in f32
+        scores = jax.lax.dot_general(
+            q_ref[...], lat, (((1,), (1,)), ((), ())),
+            preferred_element_type=F32,
+        ) * scale  # [H, bt]
+        scores = jnp.where(ok, scores, NEG_INF)
+        v = jnp.where(ok_v, lat[:, :vd].astype(F32), 0.0)  # [bt, R]
+        m_prev, s_prev = m_s[...], s_s[...]  # [H, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
+        p = jnp.exp(scores - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        s_s[...] = s_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=F32
+        )
+        m_s[...] = m_new
+
+    _paged_blocks(pt_ref, pos_ref, ((lat_hbm, buf),), sems, cur_ref,
+                  lambda: _init_softmax(m_s, s_s, acc_s), compute,
+                  npg=npg, ppb=ppb, page=page)
+
+    @pl.when(i == nblk - 1)
+    def _emit():
+        o_ref[...] = (acc_s[...] / jnp.maximum(s_s[...], 1e-30)).astype(
+            o_ref.dtype
+        )
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "v_dim", "interpret"))
+def paged_latent_attn(
+    q: jax.Array,  # [B, H, W]: absorbed query (R), roped query (pe), zeros
+    lat: jax.Array,  # [P, page, W] latent page pool: ckv (R), kpe (pe), zeros
+    page_table: jax.Array,  # [B, NP] i32, -1 = unallocated
+    pos: jax.Array,  # [B] i32 per-slot depth (position pos is attended)
+    *,
+    scale: float,
+    v_dim: int,
+    interpret: bool = False,
+) -> jax.Array:
+    """Paged absorbed-MLA decode attention -> [B, H, v_dim].
+
+    Every query head of a slot attends the slot's one latent stream:
+    scores ``q . lat`` over all W columns times ``scale``, values the first
+    ``v_dim`` columns of the same rows, so each attended page is read once
+    for all heads. The grid, the block gather (``ppb`` pages of ~1 MiB a
+    step, the next attended block prefetched) and the f32 online softmax
+    are those of :func:`paged_decode_attn`."""
+    b, h, w = q.shape
+    _, page, _ = lat.shape
+    npg = page_table.shape[1]
+    ppb = max(1, min(npg, _PAGED_BLOCK_BYTES
+                     // (page * w * lat.dtype.itemsize)))
+    pt = jnp.asarray(page_table, jnp.int32).reshape(-1)  # 1-D in SMEM
+    posr = jnp.asarray(pos, jnp.int32)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, pl.cdiv(npg, ppb)),
+        in_specs=[
+            pl.BlockSpec((None, h, w), lambda i, j, pt, ps: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec(
+            (None, h, v_dim), lambda i, j, pt, ps: (i, 0, 0)
+        ),
+        scratch_shapes=[
+            pltpu.VMEM((2, ppb, page, w), lat.dtype),
+            pltpu.SemaphoreType.DMA((1, 2)),  # (pool, buffer)
+            pltpu.SMEM((1,), jnp.int32),  # the buffer in flight for this step
+            pltpu.VMEM((h, 1), F32),
+            pltpu.VMEM((h, 1), F32),
+            pltpu.VMEM((h, v_dim), F32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_paged_latent_kernel, npg=npg, ppb=ppb,
+                          scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, v_dim), q.dtype),
+        # the prefetch chain crosses slots: both axes run in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")
+        ),
+        interpret=interpret,
+    )(pt, posr, q, lat)
 
 
 @functools.partial(jax.jit, static_argnames=("bt", "interpret"))

@@ -135,6 +135,30 @@ def paged_decode_attn(
     )
 
 
+def paged_latent_attn(
+    q: jax.Array,
+    lat: jax.Array,
+    page_table: jax.Array,
+    pos: jax.Array,
+    *,
+    scale: float,
+    v_dim: int,
+    impl: Optional[str] = None,
+) -> jax.Array:
+    """Absorbed-MLA decode attention through the latent page pool (see
+    ``kernels.decode_attn.paged_latent_attn``): q [B,H,W], pool
+    [P,page,W], page_table [B,NP] (-1 = unallocated), pos [B] ->
+    [B,H,v_dim]."""
+    impl = resolve(impl)
+    if impl == "ref":
+        return _ref.paged_latent_attn_ref(q, lat, page_table, pos, scale,
+                                          v_dim)
+    return _da.paged_latent_attn(
+        q, lat, page_table, pos, scale=scale, v_dim=v_dim,
+        interpret=(impl == "interpret"),
+    )
+
+
 # ---------------------------------------------------------------------------
 # fused recycle-ledger record+priority (no vjp — the ledger is not a
 # differentiable quantity; it is stop_gradient state by construction)

@@ -85,6 +85,32 @@ def paged_decode_attn_ref(
     return decode_attn_ref(q, k, v, valid)
 
 
+def paged_latent_attn_ref(
+    q: Array,  # [B, H, W] absorbed query then roped query
+    lat: Array,  # [P, page, W] latent page pool
+    page_table: Array,  # [B, NP] i32
+    pos: Array,  # [B] i32; position pos is attended
+    scale: float,
+    v_dim: int,
+) -> Array:
+    """Absorbed-MLA decode attention through the latent pool -> [B, H,
+    v_dim]: each row's pages gathered back to [B, T, W], f32 scores of
+    every head against all W columns, masked to ``t <= pos``, and the
+    softmax-weighted sum of the first ``v_dim`` columns (the chain of
+    ``models.layers.mla_decode``). Unallocated entries clamp to page 0,
+    whose reads are masked."""
+    b = q.shape[0]
+    _, page, w = lat.shape
+    t = page_table.shape[1] * page
+    dense = lat[jnp.maximum(page_table, 0)].reshape(b, t, w)
+    scores = jnp.einsum("bhw,btw->bht", q, dense,
+                        preferred_element_type=jnp.float32) * scale
+    valid = jnp.arange(t)[None] <= pos[:, None]
+    scores = jnp.where(valid[:, None], scores, -1e30)
+    wts = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bht,btr->bhr", wts, dense[..., :v_dim])
+
+
 def ledger_record_priority_ref(
     ema: Array,  # [capacity] f32
     count: Array,  # [capacity] i32

@@ -35,6 +35,15 @@ class ModelConfig:
     qk_rope_head_dim: int = 0
     qk_nope_head_dim: int = 0
     v_head_dim: int = 0
+    # DeepSeek's YaRN (``rope_scaling`` of type "yarn"): interpolated
+    # rope frequencies and the softmax scale times mscale(factor,
+    # mscale_all_dim)^2. 0 = plain rope at rope_theta.
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # -- FFN ------------------------------------------------------------------
     d_ff: int = 0  # dense FFN size

@@ -12,10 +12,12 @@ Conventions:
 
 from __future__ import annotations
 
+import math
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.config import ModelConfig
 from repro.models.params import ParamSpec
@@ -41,20 +43,75 @@ def rmsnorm_spec(d: int, axis: Optional[str] = "embed") -> ParamSpec:
     return ParamSpec((d,), (axis,), init="ones")
 
 
-def rope(x: Array, positions: Array, theta: float) -> Array:
+def rope(
+    x: Array, positions: Array, theta: float, inv: Any = None,
+    scale: float = 1.0,
+) -> Array:
     """Rotary embedding over the last dim. x [..., S, H, D]; positions [S]
     (shared across the batch) or [B, S] (per-example positions — the
     continuous-batching decode path, where every slot sits at its own
-    depth)."""
+    depth). ``inv`` [D/2] replaces the plain inverse frequencies of
+    ``theta`` and ``scale`` multiplies cos and sin (YaRN, :func:`rope_cfg`).
+    """
     d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=F32) / d))
+    if inv is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=F32) / d))
     ang = positions.astype(F32)[..., None] * inv  # [S, D/2]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     cos = cos[..., None, :]  # broadcast over heads: [S, 1, D/2]
     sin = sin[..., None, :]
     x1, x2 = jnp.split(x.astype(F32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """DeepSeek's ``yarn_get_mscale``: 0.1 * mscale * ln(factor) + 1."""
+    if factor <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(cfg: ModelConfig, dim: int) -> np.ndarray:
+    """DeepSeek-V2's YaRN inverse frequencies ([dim/2] float32), as
+    ``DeepseekV2YarnRotaryEmbedding`` computes them: rotary dims below the
+    correction range of ``beta_fast`` / ``beta_slow`` keep the plain
+    frequency, dims above it take the frequency over ``factor``, and a
+    linear ramp joins the two."""
+    base, f = cfg.rope_theta, cfg.yarn_factor
+    ar = np.arange(0, dim, 2, dtype=np.float32) / np.float32(dim)
+    extra = (1.0 / (np.float32(base) ** ar)).astype(np.float32)
+    inter = (1.0 / (np.float32(f) * np.float32(base) ** ar)).astype(
+        np.float32
+    )
+
+    def corr_dim(rot: float) -> float:
+        return (dim * math.log(cfg.yarn_original_max_pos
+                               / (rot * 2 * math.pi))) / (2 * math.log(base))
+
+    low = max(math.floor(corr_dim(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(corr_dim(cfg.yarn_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1).astype(np.float32)
+    keep = 1.0 - ramp  # 1 where the plain frequency is kept
+    return (inter * (1 - keep) + extra * keep).astype(np.float32)
+
+
+def rope_cfg(x: Array, positions: Array, cfg: ModelConfig) -> Array:
+    """:func:`rope` as the config asks: plain at ``rope_theta``, or
+    DeepSeek's YaRN when ``yarn_factor`` is set (cos and sin times
+    mscale(factor, mscale) / mscale(factor, mscale_all_dim), 1 where both
+    are equal)."""
+    if not cfg.yarn_factor:
+        return rope(x, positions, cfg.rope_theta)
+    scale = (yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+             / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+    return rope(x, positions, cfg.rope_theta,
+                jnp.asarray(yarn_inv_freq(cfg, x.shape[-1])), scale)
 
 
 # ---------------------------------------------------------------------------
@@ -417,10 +474,16 @@ def mla_specs(cfg: ModelConfig) -> dict[str, ParamSpec]:
     r, qr = cfg.kv_lora_rank, cfg.q_lora_rank
     nope, pe, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     s = d**-0.5
+    if qr:  # q through a low-rank latent (DeepSeek-V2)
+        q = {
+            "wq_a": ParamSpec((d, qr), ("embed", "q_lora"), scale=s),
+            "q_norm": rmsnorm_spec(qr, "q_lora"),
+            "wq_b": ParamSpec((qr, h, nope + pe), ("q_lora", "heads", "head_dim"), scale=qr**-0.5),
+        }
+    else:  # q_lora_rank null (DeepSeek-V2-Lite): one [d, h, nope + pe]
+        q = {"wq": ParamSpec((d, h, nope + pe), ("embed", "heads", "head_dim"), scale=s)}
     return {
-        "wq_a": ParamSpec((d, qr), ("embed", "q_lora"), scale=s),
-        "q_norm": rmsnorm_spec(qr, "q_lora"),
-        "wq_b": ParamSpec((qr, h, nope + pe), ("q_lora", "heads", "head_dim"), scale=qr**-0.5),
+        **q,
         "wkv_a": ParamSpec((d, r + pe), ("embed", "kv_lora"), scale=s),
         "kv_norm": rmsnorm_spec(r, "kv_lora"),
         "wkv_b": ParamSpec((r, h, nope + vd), ("kv_lora", "heads", "head_dim"), scale=r**-0.5),
@@ -428,13 +491,30 @@ def mla_specs(cfg: ModelConfig) -> dict[str, ParamSpec]:
     }
 
 
+def _yarn_mscale2(cfg: ModelConfig) -> float:
+    """mscale(factor, mscale_all_dim)^2 under DeepSeek's YaRN (~1.590 at
+    factor 40, mscale_all_dim 0.707); 1 without it."""
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        return yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return 1.0
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """The MLA softmax scale: (nope + pe)^-0.5, times YaRN's mscale^2."""
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 \
+        * _yarn_mscale2(cfg)
+
+
 def _mla_q(x: Array, p: dict, cfg: ModelConfig, positions: Array):
     dt = x.dtype
     nope, pe = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    cq = rmsnorm(jnp.einsum("bsd,dr->bsr", x, p["wq_a"].astype(dt)), p["q_norm"], cfg.norm_eps)
-    q = jnp.einsum("bsr,rhk->bshk", cq, p["wq_b"].astype(dt))
+    if "wq" in p:
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
+    else:
+        cq = rmsnorm(jnp.einsum("bsd,dr->bsr", x, p["wq_a"].astype(dt)), p["q_norm"], cfg.norm_eps)
+        q = jnp.einsum("bsr,rhk->bshk", cq, p["wq_b"].astype(dt))
     q_nope, q_pe = q[..., :nope], q[..., nope:]
-    q_pe = rope(q_pe, positions, cfg.rope_theta)
+    q_pe = rope_cfg(q_pe, positions, cfg)
     return q_nope, q_pe
 
 
@@ -444,7 +524,7 @@ def _mla_kv_latent(x: Array, p: dict, cfg: ModelConfig, positions: Array):
     kv_a = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"].astype(dt))
     ckv, k_pe = kv_a[..., :r], kv_a[..., r:]
     ckv = rmsnorm(ckv, p["kv_norm"], cfg.norm_eps)
-    k_pe = rope(k_pe[..., None, :], positions, cfg.rope_theta)[..., 0, :]
+    k_pe = rope_cfg(k_pe[..., None, :], positions, cfg)[..., 0, :]
     return ckv, k_pe  # [B,S,R], [B,S,pe]
 
 
@@ -472,14 +552,15 @@ def mla_attend(x: Array, p: dict, cfg: ModelConfig, positions: Array) -> Array:
     q_pe = _ul(q_pe, "heads")
     k_nope = _ul(k_nope, "heads")
     v = _ul(v, "heads")
-    scale_fix = (nope + cfg.qk_rope_head_dim) ** -0.5
+    scale_fix = mla_softmax_scale(cfg)
     if x.shape[1] >= cfg.blocked_attn_min:
         qcat = jnp.concatenate([q_nope, q_pe], axis=-1)
+        if _yarn_mscale2(cfg) != 1.0:  # _gqa_blocked scales by d_qk^-0.5
+            qcat = qcat * _yarn_mscale2(cfg)
         kcat = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_pe[:, :, None, :], q_pe.shape[:1] + (k_pe.shape[1], h, k_pe.shape[-1]))],
             axis=-1,
         )
-        # _gqa_blocked scales by d_qk^-0.5 internally; MLA wants the same.
         out = _gqa_blocked(qcat, kcat, v, positions, None)
     else:
         scores = (
@@ -543,7 +624,7 @@ def mla_decode(
     wkv_k = p["wkv_b"][..., :nope].astype(dt)  # [R, H, nope]
     wkv_v = p["wkv_b"][..., nope:].astype(dt)  # [R, H, vd]
     q_lat = jnp.einsum("bshk,rhk->bshr", q_nope, wkv_k)
-    scale = (nope + cfg.qk_rope_head_dim) ** -0.5
+    scale = mla_softmax_scale(cfg)
     scores = (
         jnp.einsum("bshr,btr->bhst", q_lat, ckv, preferred_element_type=F32)
         + jnp.einsum("bshk,btk->bhst", q_pe, kpe, preferred_element_type=F32)
@@ -559,6 +640,67 @@ def mla_decode(
     out = jnp.einsum("bshr,rhv->bshv", ctx, wkv_v)
     out = jnp.einsum("bshv,hvd->bsd", out, p["wo"].astype(dt))
     return out, {"ckv": ckv, "kpe": kpe}
+
+
+def mla_latent_width(cfg: ModelConfig) -> int:
+    """Columns of a latent page row: R + pe rounded up to the 128 lanes
+    of a TPU tile (576 -> 640 at DeepSeek-V2's widths), the width the
+    chip's tiled layout allocates anyway and the kernel's DMA can slice."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+
+
+def mla_paged_init_cache(
+    cfg: ModelConfig, num_pages: int, page_size: int, dtype
+) -> dict:
+    """One layer's slice of the latent page pool: [P, page, W].
+
+    A token holds one latent row: the normed ``ckv`` (R columns), the
+    roped ``kpe`` (pe columns), then zeros up to :func:`mla_latent_width`.
+    At DeepSeek-V2's widths in bf16 that is 576 x 2 B = 1,152 B of latent
+    in 640 x 2 B = 1,280 B allocated a token a layer."""
+    return {"lat": jnp.zeros((num_pages, page_size, mla_latent_width(cfg)),
+                             dtype)}
+
+
+def mla_paged_decode(
+    x: Array, p: dict, cfg: ModelConfig, cache: dict, page_table: Array,
+    pos: Array,
+) -> tuple[Array, dict]:
+    """Absorbed-weight decode through the latent page pool.
+
+    x [B,1,D]; cache {"lat": [P, page, W]}; page_table [B, NP] (-1 =
+    unallocated: the write is dropped, as in ``gqa_paged_decode``); pos [B]
+    per-slot depth. The new token's latent is written at ``pos``; the query
+    absorbs wkv_b's K half ([B, H, R] beside the roped [B, H, pe], zeros
+    to W) and
+    attends one latent stream for all heads, whose first R columns are the
+    values (``kernels.ops.paged_latent_attn``); wkv_b's V half and wo then
+    apply to the weighted latent sum, as in :func:`mla_decode`."""
+    from repro.kernels import ops as kops
+
+    dt = x.dtype
+    nope, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    b = x.shape[0]
+    q_nope, q_pe = _mla_q(x, p, cfg, pos[:, None])
+    ckv, kpe = _mla_kv_latent(x, p, cfg, pos[:, None])
+    pool = cache["lat"]
+    ps, w = pool.shape[1], pool.shape[2]
+    pad = [(0, 0), (0, 0), (0, w - r - cfg.qk_rope_head_dim)]
+    page = page_table[jnp.arange(b), pos // ps]
+    # -1 -> one-past-end before the scatter (a raw -1 wraps, see
+    # gqa_paged_decode)
+    page = jnp.where(page >= 0, page, pool.shape[0])
+    row = jnp.pad(jnp.concatenate([ckv, kpe], -1), pad)[:, 0]  # [B, W]
+    lat = pool.at[page, pos % ps].set(row.astype(pool.dtype), mode="drop")
+    q_lat = jnp.einsum("bshk,rhk->bshr", q_nope,
+                       p["wkv_b"][..., :nope].astype(dt))
+    q = jnp.pad(jnp.concatenate([q_lat, q_pe], -1)[:, 0], pad)  # [B, H, W]
+    ctx = kops.paged_latent_attn(q, lat, page_table, pos,
+                                 scale=mla_softmax_scale(cfg), v_dim=r)
+    out = jnp.einsum("bhr,rhv->bhv", ctx.astype(dt),
+                     p["wkv_b"][..., nope:].astype(dt))
+    out = jnp.einsum("bhv,hvd->bd", out, p["wo"].astype(dt))[:, None]
+    return out, {"lat": lat}
 
 
 # ---------------------------------------------------------------------------

@@ -379,8 +379,8 @@ def _attn_block_fill(x, p, cfg, positions, max_seq, ffn):
     a, cache = _attn_fill(h, p["attn"], cfg, positions, max_seq)
     x = x + a
     h = L.rmsnorm(x, p["ffn_norm"], cfg.norm_eps)
-    if ffn == "moe":
-        out, _ = M.moe_ffn(h, p["moe"], cfg)
+    if ffn == "moe":  # serving: drop-free, so a pad changes no real token
+        out, _ = M.moe_ffn_dropless(h, p["moe"], cfg)
     else:
         out = L.mlp(h, p["mlp"])
     return activation_constraint(x + out, "residual"), cache
@@ -403,31 +403,37 @@ def _scan_fill(body, x, stacked, remat):
 
 
 def init_paged_cache(cfg: ModelConfig, num_pages: int, page_size: int) -> dict:
-    """Global paged KV pool, stacked over layers: [L, P, kv, page, hd].
+    """Global paged KV pool, stacked over layers like the parameters.
 
-    A physical page id addresses the same page across every layer, so one
-    per-slot page table serves the whole stack (the vLLM block-table
-    layout). Only plain-GQA causal families qualify: recurrent state, MoE
-    capacity, latent (MLA) caches, rolling SWA windows and int8-quantized
-    caches all keep the dense per-slot layout."""
-    if cfg.family not in ("dense", "audio", "vlm"):
+    GQA layers hold K and V pages [L, P, kv, page, hd]; MLA layers one
+    latent page pool [L, P, page, W] (``layers.mla_paged_init_cache``); an
+    MoE family with leading dense layers keeps their pool under
+    ``dense_blocks``. A physical page id addresses the same page across
+    every layer, so one per-slot page table serves the whole stack (the
+    vLLM block-table layout). Causal attention families qualify, dense or
+    MoE (whose serving layer is drop-free); recurrent state (SSM, hybrid),
+    rolling SWA windows and int8-quantized caches keep the dense per-slot
+    layout."""
+    if cfg.family not in ("dense", "audio", "vlm", "moe"):
         raise NotImplementedError(
-            f"paged KV cache: family {cfg.family!r} has non-KV or "
-            "capacity-coupled cache state"
+            f"paged KV cache: family {cfg.family!r} has non-KV cache state"
         )
-    if cfg.attn_impl == "mla" or cfg.sliding_window is not None:
+    if cfg.sliding_window is not None:
         raise NotImplementedError(
-            "paged KV cache requires plain GQA without a sliding window"
+            "paged KV cache requires attention without a sliding window"
         )
     if cfg.kv_cache_dtype == "int8":
         raise NotImplementedError("paged KV cache: int8 KV not supported yet")
     dt = jnp.dtype(cfg.compute_dtype)
-    return {
-        "blocks": _stack_over(
-            cfg.num_layers,
-            lambda: L.gqa_paged_init_cache(cfg, num_pages, page_size, dt),
-        )
-    }
+    init = (L.mla_paged_init_cache if cfg.attn_impl == "mla"
+            else L.gqa_paged_init_cache)
+    make = lambda: init(cfg, num_pages, page_size, dt)
+    if cfg.family != "moe":
+        return {"blocks": _stack_over(cfg.num_layers, make)}
+    cache = {"blocks": _stack_over(cfg.num_layers - cfg.first_k_dense, make)}
+    if cfg.first_k_dense:
+        cache["dense_blocks"] = _stack_over(cfg.first_k_dense, make)
+    return cache
 
 
 def prefill(
@@ -498,8 +504,12 @@ def prefill(
 
 
 def _attn_block_decode(x, p, cfg, cache, pos, max_seq, ffn, page_table=None):
+    """One decode layer -> (x, (cache, tokens routed to each expert [E],
+    None for a dense FFN))."""
     h = L.rmsnorm(x, p["attn_norm"], cfg.norm_eps)
-    if page_table is not None:
+    if page_table is not None and cfg.attn_impl == "mla":
+        a, cache = L.mla_paged_decode(h, p["attn"], cfg, cache, page_table, pos)
+    elif page_table is not None:
         a, cache = L.gqa_paged_decode(h, p["attn"], cfg, cache, page_table, pos)
     elif cfg.attn_impl == "mla":
         a, cache = L.mla_decode(h, p["attn"], cfg, cache, pos, max_seq)
@@ -507,11 +517,12 @@ def _attn_block_decode(x, p, cfg, cache, pos, max_seq, ffn, page_table=None):
         a, cache = L.gqa_decode(h, p["attn"], cfg, cache, pos, max_seq)
     x = x + a
     h = L.rmsnorm(x, p["ffn_norm"], cfg.norm_eps)
-    if ffn == "moe":
-        out, _ = M.moe_ffn(h, p["moe"], cfg)
+    counts = None
+    if ffn == "moe":  # serving: drop-free, as in prefill
+        out, counts = M.moe_ffn_dropless(h, p["moe"], cfg)
     else:
         out = L.mlp(h, p["mlp"])
-    return x + out, cache
+    return x + out, (cache, counts)
 
 
 def _ssm_block_decode(x, p, cfg, cache):
@@ -522,8 +533,8 @@ def _ssm_block_decode(x, p, cfg, cache):
 
 def decode_step(
     params: dict, cfg: ModelConfig, cache: dict, tokens: Array, pos: Array,
-    page_table: Optional[Array] = None,
-) -> tuple[Array, dict]:
+    page_table: Optional[Array] = None, routing: bool = False,
+) -> tuple:
     """One decode step: tokens [B,1] -> (logits [B,V], cache).
 
     ``pos`` is the number of tokens already in the cache: a scalar when the
@@ -535,35 +546,35 @@ def decode_step(
     ``page_table`` ([B, NP] i32, -1 = unallocated) switches the attention
     cache to the paged layout of :func:`init_paged_cache`: K/V writes and
     reads go through the table instead of a per-slot dense reservation.
+
+    ``routing`` (MoE families) also returns the tokens each MoE layer
+    routed to each expert, [L_moe, E] i32, every row of the batch counted.
     """
     x = embed_tokens(params, cfg, tokens, None)
     new_cache: dict = {}
+    counts = None
 
-    if page_table is not None:
-        if cfg.family not in ("dense", "audio", "vlm"):
-            raise NotImplementedError(
-                f"paged decode: unsupported family {cfg.family!r}"
-            )
-        body = lambda x, lpc: _attn_block_decode(
-            x, lpc[0], cfg, lpc[1], pos, 0, "dense", page_table
+    if page_table is not None and cfg.family not in (
+        "dense", "audio", "vlm", "moe"
+    ):
+        raise NotImplementedError(
+            f"paged decode: unsupported family {cfg.family!r}"
         )
-        x, new_cache["blocks"] = jax.lax.scan(
-            body, x, (params["blocks"], cache["blocks"])
-        )
-    elif cfg.family in ("dense", "audio", "vlm", "moe"):
+    if cfg.family in ("dense", "audio", "vlm", "moe"):
         ffn = "moe" if cfg.family == "moe" else "dense"
-        max_seq = _attn_cache_capacity(cfg, cache["blocks"])
+        max_seq = (0 if page_table is not None
+                   else _attn_cache_capacity(cfg, cache["blocks"]))
         if cfg.family == "moe" and cfg.first_k_dense:
             body = lambda x, lpc: _attn_block_decode(
-                x, lpc[0], cfg, lpc[1], pos, max_seq, "dense"
+                x, lpc[0], cfg, lpc[1], pos, max_seq, "dense", page_table
             )
-            x, new_cache["dense_blocks"] = jax.lax.scan(
+            x, (new_cache["dense_blocks"], _) = jax.lax.scan(
                 body, x, (params["dense_blocks"], cache["dense_blocks"])
             )
         body = lambda x, lpc: _attn_block_decode(
-            x, lpc[0], cfg, lpc[1], pos, max_seq, ffn
+            x, lpc[0], cfg, lpc[1], pos, max_seq, ffn, page_table
         )
-        x, new_cache["blocks"] = jax.lax.scan(
+        x, (new_cache["blocks"], counts) = jax.lax.scan(
             body, x, (params["blocks"], cache["blocks"])
         )
     elif cfg.family == "ssm":
@@ -599,6 +610,8 @@ def decode_step(
 
     x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed(params, cfg, x)[:, 0, :]
+    if routing:
+        return logits, new_cache, counts
     return logits, new_cache
 
 

@@ -1,15 +1,25 @@
 """Mixture-of-Experts FFN (Mixtral top-2, DeepSeek-V2 shared+routed top-6).
 
-GShard-style dense dispatch, the TPU-idiomatic formulation: tokens are
-grouped (one group per sequence), each group dispatches into per-expert
-capacity slots via one-hot einsums, expert FFNs run as a single stacked
-einsum over the expert axis, and a combine einsum scatters results back.
-Everything is static-shaped, so it pjit-shards cleanly: the expert axis maps
-to the "model" mesh axis (expert parallelism) and groups follow the batch.
+Two layers over one gate, :func:`route` (router logits in f32, softmax,
+greedy top-k, renormalised only where ``route_norm``); only the dispatch
+differs between them:
 
-Capacity overflow drops tokens (their FFN output is 0 and the residual
-passes through) — the standard trade at scale; `capacity_factor` controls
-the drop rate and tests assert zero drops at cf >= k with balanced routers.
+* :func:`moe_ffn`, the training path: GShard-style dense dispatch, the
+  TPU-idiomatic formulation: tokens are grouped (one group per sequence),
+  each group dispatches into per-expert capacity slots via one-hot
+  einsums, expert FFNs run as a single stacked einsum over the expert
+  axis, and a combine einsum scatters results back. Everything is
+  static-shaped, so it pjit-shards cleanly: the expert axis maps to the
+  "model" mesh axis (expert parallelism) and groups follow the batch.
+  Capacity overflow drops tokens (their FFN output is 0 and the residual
+  passes through) — the standard trade at scale; `capacity_factor`
+  controls the drop rate and tests assert zero drops at cf >= k with
+  balanced routers.
+* :func:`moe_ffn_dropless`, the serving path (prefill and decode): every
+  token-expert pair is computed. Pairs are sorted by expert, run through
+  grouped matmuls (``jax.lax.ragged_dot``), unsorted and weighted by their
+  gates. No token competes with another for a slot, so a right-pad can
+  never change a real token's output.
 """
 
 from __future__ import annotations
@@ -39,6 +49,9 @@ def moe_specs(cfg: ModelConfig) -> dict[str, ParamSpec]:
 
 
 def capacity(cfg: ModelConfig, group_tokens: int) -> int:
+    """Slots per expert and group of :func:`moe_ffn` (the training path;
+    the dropless serving path has none): at least 4, so a one-token decode
+    group still computes 4 rows per expert."""
     c = int(
         group_tokens
         / cfg.num_experts
@@ -48,11 +61,16 @@ def capacity(cfg: ModelConfig, group_tokens: int) -> int:
     return max(4, -(-c // 4) * 4)  # >=4, rounded up to a multiple of 4
 
 
-def _top_k_gates(logits: Array, k: int, renormalize: bool):
-    """logits [G,S,E] f32 -> (gates [G,S,K], expert_idx [G,S,K], probs)."""
+def route(x: Array, router: Array, cfg: ModelConfig):
+    """x [..., D] -> (gates [..., K] f32, expert_idx [..., K], probs [..., E]).
+
+    The logits are computed in f32, as DeepSeek-V2's MoEGate computes them:
+    a bf16 logit near a tie would send a token to another expert, so the
+    trainer and the server would route one token apart."""
+    logits = jnp.einsum("...d,de->...e", x.astype(F32), router.astype(F32))
     probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = jax.lax.top_k(probs, k)
-    if renormalize:  # Mixtral renormalizes the top-k; DeepSeek-V2 does not
+    gates, idx = jax.lax.top_k(probs, cfg.experts_per_token)
+    if cfg.route_norm:  # Mixtral renormalizes the top-k; DeepSeek-V2 does not
         gates = gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
     return gates, idx, probs
 
@@ -101,11 +119,10 @@ def moe_ffn(
     if regroup:
         x = x.reshape(bsz * (seq // gs), gs, d)
     g, s, _ = x.shape
-    e, k = cfg.num_experts, cfg.experts_per_token
+    e = cfg.num_experts
     c = capacity(cfg, s)
 
-    logits = jnp.einsum("gsd,de->gse", x, p["router"].astype(dt)).astype(F32)
-    gates, idx, probs = _top_k_gates(logits, k, renormalize=cfg.route_norm)
+    gates, idx, probs = route(x, p["router"], cfg)
     aux = load_balance_loss(probs, idx, e)
     disp, comb = _dispatch_combine(idx, gates, e, c)
 
@@ -121,6 +138,36 @@ def moe_ffn(
     if regroup:
         out = out.reshape(bsz, seq, d)
     return out, aux
+
+
+def moe_ffn_dropless(
+    x: Array, p: dict, cfg: ModelConfig
+) -> tuple[Array, Array]:
+    """x [B,S,D] -> (out [B,S,D], tokens routed to each expert [E] i32).
+
+    Every token's top-k pairs are sorted by expert (stably, so a token's
+    pairs keep their order within an expert), gathered into one [N*k, D]
+    operand whose rows run through each expert's SwiGLU as grouped
+    matmuls, put back in pair order, and summed per token with the gate
+    weights in f32; the shared experts add their dense MLP."""
+    dt = x.dtype
+    bsz, seq, d = x.shape
+    e, k = cfg.num_experts, cfg.experts_per_token
+    xf = x.reshape(bsz * seq, d)
+    gates, idx, _ = route(xf, p["router"], cfg)
+    flat = idx.reshape(-1)  # [N*k] expert of each pair, token-major
+    order = jnp.argsort(flat, stable=True)
+    counts = jnp.bincount(flat, length=e).astype(jnp.int32)
+    xs = xf[order // k]  # [N*k, D], grouped by expert
+    h = jax.nn.silu(jax.lax.ragged_dot(xs, p["w1"].astype(dt), counts))
+    h = h * jax.lax.ragged_dot(xs, p["w3"].astype(dt), counts)
+    ys = jax.lax.ragged_dot(h, p["w2"].astype(dt), counts)
+    y = ys[jnp.argsort(order)].reshape(bsz * seq, k, d)  # pair order
+    out = jnp.einsum("nk,nkd->nd", gates, y.astype(F32)).astype(dt)
+    out = out.reshape(bsz, seq, d)
+    if cfg.num_shared_experts:
+        out = out + mlp(x, p["shared"])
+    return out, counts
 
 
 def routing_stats(logits: Array, k: int) -> dict[str, Array]:

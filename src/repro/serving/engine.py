@@ -49,9 +49,10 @@ Array = jax.Array
 I32 = jnp.int32
 
 # Families where a right-padded prompt cannot perturb real positions:
-# causal attention only (no recurrent state integrating pads, no MoE
-# capacity competition, no rolling sliding-window cache layout).
-_PAD_SAFE_FAMILIES = ("dense", "vlm", "audio")
+# causal attention, with a dense FFN or the drop-free MoE layer that
+# serving runs (no token competes for expert capacity). Recurrent state
+# integrates pads, and a rolling sliding-window cache shifts by them.
+_PAD_SAFE_FAMILIES = ("dense", "vlm", "audio", "moe")
 
 
 def pad_safe(cfg: ModelConfig) -> bool:
@@ -132,30 +133,38 @@ def insert_paged_cache_slot(
     ``pt_row`` [NP] maps the slot's logical blocks to physical pages of the
     global pool; -1 entries (blocks not yet allocated — growth pages, or the
     tail past the prompt bucket) drop their writes. The prefill cache is
-    dense [L, 1, T, kv, hd] and the pool [L, P, kv, page, hd]; T need not
-    fill NP pages — the tail pads with zeros, which only lands in allocated
-    pages past the prompt where decode overwrites it before validity ever
-    reaches it.
+    dense: [L, 1, T, kv, hd] K and V for the pool's [L, P, kv, page, hd],
+    or [L, 1, T, R] ``ckv`` and [L, 1, T, pe] ``kpe`` for the latent pool
+    [L, P, page, W] (one row, zero-padded to W). T need not fill NP pages —
+    the tail pads with zeros, which only lands in allocated pages past the
+    prompt where decode overwrites it before validity ever reaches it.
     """
     npg = pt_row.shape[0]
 
     def put(pool, dense):
-        l, _, t, kv, hd = dense.shape
-        pad = npg * page_size - t
-        d = jnp.pad(dense[:, 0], [(0, 0), (0, pad), (0, 0), (0, 0)])
-        d = d.reshape(l, npg, page_size, kv, hd).swapaxes(2, 3)
+        l, _, t = dense.shape[:3]
+        pad = [(0, 0), (0, npg * page_size - t)]
+        d = jnp.pad(dense[:, 0], pad + [(0, 0)] * (dense.ndim - 3))
+        d = d.reshape(l, npg, page_size, *dense.shape[3:])
+        if d.ndim == 5:  # K/V: head-major pages [kv, page, hd]
+            d = d.swapaxes(2, 3)
         # -1 would WRAP to the pool's last page (negative indices resolve
         # numpy-style before mode="drop" sees them) — remap to one-past-end
         idx = jnp.where(pt_row >= 0, pt_row, pool.shape[1])
         return pool.at[:, idx].set(d, mode="drop")
 
-    blocks = cache["blocks"]
-    return {
-        "blocks": {
-            "kp": put(blocks["kp"], new["blocks"]["k"]),
-            "vp": put(blocks["vp"], new["blocks"]["v"]),
-        }
-    }
+    out = {}
+    for key, sub in cache.items():
+        n = new[key]
+        if "lat" in sub:
+            row = jnp.concatenate([n["ckv"], n["kpe"]], -1)
+            w = sub["lat"].shape[-1]
+            row = jnp.pad(row, [(0, 0)] * 3 + [(0, w - row.shape[-1])])
+            out[key] = {"lat": put(sub["lat"], row.astype(sub["lat"].dtype))}
+        else:
+            out[key] = {"kp": put(sub["kp"], n["k"]),
+                        "vp": put(sub["vp"], n["v"])}
+    return out
 
 
 def make_slot_sampler(temperature: float, top_p: float, seed: int):
@@ -200,7 +209,7 @@ class Engine:
 
     ``recorder`` owns ledger placement; ``prompt_buckets`` pads prompts up
     to the nearest bucket so distinct lengths share one prefill compile
-    (pad-safe families only — recurrent/MoE/windowed families prefill at
+    (pad-safe families only — recurrent and windowed families prefill at
     exact length, one compile per distinct length).
     """
 
@@ -275,9 +284,8 @@ class Engine:
         if prompt_buckets is not None and not pad_safe(cfg):
             raise ValueError(
                 f"{cfg.family} family (or sliding-window attention) cannot "
-                "right-pad prompts (pads perturb recurrent state / MoE "
-                "capacity / rolling caches); use exact-length prefill "
-                "(prompt_buckets=None)"
+                "right-pad prompts (pads perturb recurrent state / rolling "
+                "caches); use exact-length prefill (prompt_buckets=None)"
             )
         self.prompt_buckets = (
             tuple(sorted(prompt_buckets)) if prompt_buckets else None
@@ -446,11 +454,13 @@ class Engine:
         one jit, all inputs device-resident (transfer-free by design)."""
         occupied = estate.inst >= 0
         decoding = occupied & (estate.gen_idx < estate.max_new)
-        logits, cache = self.recorder.per_device(
+        routing = self.cfg.uses_moe
+        out = self.recorder.per_device(
             lambda p, c, tok, pos, pt: Mdl.decode_step(
-                p, self.cfg, c, tok, pos, page_table=pt
+                p, self.cfg, c, tok, pos, page_table=pt, routing=routing
             )
         )(params, estate.cache, estate.cur_tok, estate.pos, estate.page_table)
+        logits, cache = out[:2]
         nxt = self._sample(logits, estate.inst, estate.gen_idx)
         bidx = jnp.arange(self.slots)
         tgt = jnp.where(decoding, estate.gen_idx, self.max_gen)
@@ -490,6 +500,13 @@ class Engine:
             "n_recorded": rstate.n_recorded,
             "a2a_overflow": info["a2a_overflow"],
         }
+        if routing:  # [L_moe, E] tokens per expert, every slot's row
+            counts = out[2]
+            metrics["moe_routed"] = counts.sum()
+            metrics["moe_experts_hit"] = (counts > 0).sum()
+            metrics["moe_load_max"] = jnp.max(
+                counts.max(-1) / counts.astype(jnp.float32).mean(-1)
+            )
         return new_es, rstate, metrics
 
     def _grow_fn(self, estate, slots_arr, idxs, pages):
@@ -765,7 +782,12 @@ class Engine:
         Each phase opens its span (``engine.evict``, ``engine.admit``,
         ``engine.grow_pages``, ``engine.decode_step``,
         ``engine.fetch_metrics``, ``engine.account``): under a JAX profiler
-        session they lie on the device trace's clock."""
+        session they lie on the device trace's clock. MoE families also
+        open a zero-length ``engine.moe`` after the fetch, whose arguments
+        are the step's routing: ``routed`` token-expert pairs,
+        ``experts_hit`` (experts with a token, summed over the MoE layers)
+        and ``load_max`` (the worst layer's largest expert load over its
+        mean)."""
         t0 = time.perf_counter()
         self._evict_done()
         while self._free:
@@ -814,6 +836,14 @@ class Engine:
         self._estate, self._rstate, metrics = out
         with self.telemetry.span("engine.fetch_metrics"):
             metrics = jax.device_get(metrics)
+        if "moe_routed" in metrics:
+            # zero-length: its arguments carry the step's routing
+            with self.telemetry.span(
+                "engine.moe", routed=int(metrics["moe_routed"]),
+                experts_hit=int(metrics["moe_experts_hit"]),
+                load_max=float(metrics["moe_load_max"]),
+            ):
+                pass
         with self.telemetry.span("engine.account"):
             self._account(metrics, t0)
         return metrics

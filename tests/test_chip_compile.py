@@ -13,6 +13,7 @@ The last tests run on the CPU: they pin the dispatch rule that sends the
 main path to these kernels on a TPU.
 """
 
+import functools
 import os
 
 import jax
@@ -92,6 +93,49 @@ def test_paged_decode_attn_compiles_at_qwen3_widths(one_chip, b, npg,
         ((b,), jnp.int32),
     )
     assert "tpu_custom_call" in text
+
+
+DSV2 = configs.get("deepseek-v2-lite")  # published widths
+
+
+def test_paged_latent_attn_compiles_at_the_cell_shapes(one_chip):
+    """The dsv2lite-rag cell: 64 slots of 240 pages (3,840 positions), a
+    15,360-page pool of rows of 640 (576 latent columns to 128 lanes)."""
+    from repro.models.layers import mla_latent_width, mla_softmax_scale
+
+    w = mla_latent_width(DSV2)
+    text = _compiled_text(
+        functools.partial(DA_mod.paged_latent_attn,
+                          scale=mla_softmax_scale(DSV2),
+                          v_dim=DSV2.kv_lora_rank), one_chip,
+        ((64, DSV2.num_heads, w), jnp.bfloat16),
+        ((64 * 240, 16, w), jnp.bfloat16),
+        ((64, 240), jnp.int32),
+        ((64,), jnp.int32),
+    )
+    assert "paged_latent_attn" in text and "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("tokens", [64, 3072], ids=["decode", "prefill"])
+def test_dropless_moe_compiles_at_published_widths(one_chip, tokens):
+    """The drop-free expert layer of 64 experts of 1,408, top-6: a decode
+    step of 64 slots and a prefill of 3,072 tokens. Its grouped matmuls
+    are the ``ragged-dot`` operations ``moe_roofline`` reads."""
+    from repro.models import moe
+    from repro.models.params import ParamSpec
+
+    specs = moe.moe_specs(DSV2)
+    shapes = jax.tree.map(lambda s: (s.shape, jnp.bfloat16), specs,
+                          is_leaf=lambda x: isinstance(x, ParamSpec))
+    leaves, tree = jax.tree.flatten(shapes, is_leaf=lambda x: isinstance(
+        x, tuple) and isinstance(x[0], tuple))
+
+    def layer(x, *ps):
+        return moe.moe_ffn_dropless(x, jax.tree.unflatten(tree, ps), DSV2)
+
+    text = _compiled_text(layer, one_chip,
+                          ((1, tokens, DSV2.d_model), jnp.bfloat16), *leaves)
+    assert text.count("ragged-dot") >= 3
 
 
 @pytest.mark.parametrize("variant,batch", [("fori", 64), ("block", 512)])

@@ -405,7 +405,7 @@ def test_ledger_interchange_and_recycle_feed(params):
 
 
 def test_exact_length_families_reject_padding(params):
-    """Recurrent/MoE/windowed families must refuse prompt padding (pads
+    """Recurrent and windowed families must refuse prompt padding (pads
     would perturb real positions) but still serve via exact-length
     prefill."""
     cfg = configs.get_smoke("mamba2-370m")
