@@ -148,7 +148,7 @@ def phase_kernels(cfg, *, batch=8, ctx=4096, pages=(16, 128), rows=(32, 256),
         npg = ctx // page
         n_pool = batch * npg + 3
         k0, k1, k2 = jax.random.split(jax.random.key(page), 3)
-        shape = (n_pool, hkv, page, d)
+        shape = (1, n_pool, hkv, page, d)  # one layer of the stacked pool
         kp = jax.random.normal(k0, shape, jnp.float32).astype(jnp.bfloat16)
         vp = jax.random.normal(k1, shape, jnp.float32).astype(jnp.bfloat16)
         q = (4 * jax.random.normal(k2, (batch, hq, d))).astype(jnp.bfloat16)
@@ -160,7 +160,7 @@ def phase_kernels(cfg, *, batch=8, ctx=4096, pages=(16, 128), rows=(32, 256),
             n = int(pos[i]) // page + 1
             pt[i, :n] = perm[used:used + n]
             used += n
-        args = (q, kp, vp, jnp.asarray(pt), jnp.asarray(pos))
+        args = (q, kp, vp, jnp.asarray(pt), jnp.asarray(pos), 0)
         got = np.asarray(ops.paged_decode_attn(*args), np.float32)
         want = np.asarray(ops.paged_decode_attn(*args, impl="ref"),
                           np.float32)

@@ -15,10 +15,12 @@ cache layouts.
 
 The paged kernel (``paged_decode_attn``) reads K/V through a page table
 instead: grid (B, page blocks), each step gathering the attended pages of
-one slot for every kv head by manual DMA. The paged latent kernel
-(``paged_latent_attn``, DeepSeek-V2's absorbed MLA) shares that block
-gather (:func:`_paged_blocks`): every query head of a slot attends one
-latent stream whose first columns are also the values.
+one slot for every kv head by manual DMA, from one layer of the pool
+stacked over layers (the layer index rides in beside the table, so the
+decode step never slices a layer's pool out of the stack). The paged
+latent kernel (``paged_latent_attn``, DeepSeek-V2's absorbed MLA) shares
+that block gather (:func:`_paged_blocks`): every query head of a slot
+attends one latent stream whose first columns are also the values.
 """
 
 from __future__ import annotations
@@ -86,17 +88,19 @@ def _pages_per_block(hkv: int, page: int, d: int, itemsize: int,
     return max(1, min(npg, _PAGED_BLOCK_BYTES // (hkv * page * d * itemsize)))
 
 
-def _paged_blocks(pt_ref, pos_ref, copies, sems, cur_ref, init, compute, *,
-                  npg, ppb, page):
+def _paged_blocks(pt_ref, pos_ref, layer_ref, copies, sems, cur_ref, init,
+                  compute, *, npg, ppb, page):
     """The block gather of the paged kernels, on grid (slots, page blocks)
-    with pages minor. ``copies`` pairs each pool in HBM ([P, ...], a page
-    per leading index) with its VMEM buffer [2, ppb, ...]; a grid step
+    with pages minor. ``copies`` pairs each stacked pool in HBM ([L, P,
+    ...], a page per second index) with its VMEM buffer [2, ppb, ...]; the
+    pages are read from layer ``layer_ref[0]`` of the stack. A grid step
     gathers the attended pages of its block of one slot into buffer
     ``cur`` (one DMA per page and pool) while the next block that attends
     anything is in flight into the other one, then runs ``compute(cur)``.
     ``init`` runs at a slot's first block."""
     b, i = pl.program_id(0), pl.program_id(1)
     nslots, nblk = pl.num_programs(0), pl.num_programs(1)
+    layer = layer_ref[0]
 
     def attended(bb, ii):
         """Pages of block (bb, ii) the slot attends: logical pages up to
@@ -118,7 +122,8 @@ def _paged_blocks(pt_ref, pos_ref, copies, sems, cur_ref, init, compute, *,
             def _():
                 for n, (pool, buf) in enumerate(copies):
                     op(pltpu.make_async_copy(
-                        pool.at[phys], buf.at[slot, j], sems.at[n, slot]))
+                        pool.at[layer, phys], buf.at[slot, j],
+                        sems.at[n, slot]))
 
             return carry
 
@@ -168,6 +173,11 @@ def _paged_blocks(pt_ref, pos_ref, copies, sems, cur_ref, init, compute, *,
         compute(cur)
 
 
+def _layer_operand(layer) -> jax.Array:
+    """The layer index as the [1] i32 scalar-prefetch operand."""
+    return jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
+
+
 def _init_softmax(m_s, s_s, acc_s):
     m_s[...] = jnp.full_like(m_s, NEG_INF)
     s_s[...] = jnp.zeros_like(s_s)
@@ -175,7 +185,7 @@ def _init_softmax(m_s, s_s, acc_s):
 
 
 def _paged_decode_kernel(
-    pt_ref, pos_ref, q_ref, kp_hbm, vp_hbm, o_ref,
+    pt_ref, pos_ref, layer_ref, q_ref, kp_hbm, vp_hbm, o_ref,
     kbuf, vbuf, sems, cur_ref, m_s, s_s, acc_s, *, npg, ppb,
 ):
     b, i = pl.program_id(0), pl.program_id(1)
@@ -214,8 +224,9 @@ def _paged_decode_kernel(
             )
             m_s[h] = m_new
 
-    _paged_blocks(pt_ref, pos_ref, ((kp_hbm, kbuf), (vp_hbm, vbuf)), sems,
-                  cur_ref, lambda: _init_softmax(m_s, s_s, acc_s), compute,
+    _paged_blocks(pt_ref, pos_ref, layer_ref,
+                  ((kp_hbm, kbuf), (vp_hbm, vbuf)), sems, cur_ref,
+                  lambda: _init_softmax(m_s, s_s, acc_s), compute,
                   npg=npg, ppb=ppb, page=page)
 
     @pl.when(i == nblk - 1)
@@ -228,14 +239,17 @@ def _paged_decode_kernel(
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attn(
     q: jax.Array,  # [B, Hq, D]
-    kp: jax.Array,  # [P, Hkv, page, D] global page pool (head-major)
-    vp: jax.Array,  # [P, Hkv, page, D]
+    kp: jax.Array,  # [L, P, Hkv, page, D] global page pool (head-major)
+    vp: jax.Array,  # [L, P, Hkv, page, D]
     page_table: jax.Array,  # [B, NP] i32, -1 = unallocated
     pos: jax.Array,  # [B] i32 per-slot depth (position pos is attended)
+    layer: jax.Array,  # i32 scalar: the layer of the stack read
     *,
     interpret: bool = False,
 ) -> jax.Array:
-    """Paged flash decode attention over only the pages a slot attends.
+    """Paged flash decode attention over only the pages a slot attends,
+    in layer ``layer`` of the stacked pool (read in place: the stack is
+    never sliced).
 
     Grid (B, ceil(NP / ppb)), pages minor. A grid step gathers a block of
     up to ``ppb`` logical pages of one slot (:func:`_pages_per_block`,
@@ -252,7 +266,7 @@ def paged_decode_attn(
     reads zeros. The table is a prefix of allocated pages up to pos //
     page, as the engine keeps it."""
     b, hq, d = q.shape
-    _, hkv, page, _ = kp.shape
+    _, _, hkv, page, _ = kp.shape
     npg = page_table.shape[1]
     g = hq // hkv
     ppb = _pages_per_block(hkv, page, d, kp.dtype.itemsize, npg)
@@ -261,15 +275,15 @@ def paged_decode_attn(
     posr = jnp.asarray(pos, jnp.int32)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, pl.cdiv(npg, ppb)),
         in_specs=[
-            pl.BlockSpec((None, hkv, g, d), lambda i, j, pt, ps: (i, 0, 0, 0)),
+            pl.BlockSpec((None, hkv, g, d), lambda i, j, *_: (i, 0, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec(
-            (None, hkv, g, d), lambda i, j, pt, ps: (i, 0, 0, 0)
+            (None, hkv, g, d), lambda i, j, *_: (i, 0, 0, 0)
         ),
         scratch_shapes=[
             pltpu.VMEM((2, ppb, hkv, page, d), kp.dtype),
@@ -290,12 +304,12 @@ def paged_decode_attn(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=interpret,
-    )(pt, posr, qr, kp, vp)
+    )(pt, posr, _layer_operand(layer), qr, kp, vp)
     return out.reshape(b, hq, d)
 
 
 def _paged_latent_kernel(
-    pt_ref, pos_ref, q_ref, lat_hbm, o_ref,
+    pt_ref, pos_ref, layer_ref, q_ref, lat_hbm, o_ref,
     buf, sems, cur_ref, m_s, s_s, acc_s, *, npg, ppb, scale,
 ):
     b, i = pl.program_id(0), pl.program_id(1)
@@ -330,8 +344,8 @@ def _paged_latent_kernel(
         )
         m_s[...] = m_new
 
-    _paged_blocks(pt_ref, pos_ref, ((lat_hbm, buf),), sems, cur_ref,
-                  lambda: _init_softmax(m_s, s_s, acc_s), compute,
+    _paged_blocks(pt_ref, pos_ref, layer_ref, ((lat_hbm, buf),), sems,
+                  cur_ref, lambda: _init_softmax(m_s, s_s, acc_s), compute,
                   npg=npg, ppb=ppb, page=page)
 
     @pl.when(i == nblk - 1)
@@ -344,15 +358,17 @@ def _paged_latent_kernel(
 @functools.partial(jax.jit, static_argnames=("scale", "v_dim", "interpret"))
 def paged_latent_attn(
     q: jax.Array,  # [B, H, W]: absorbed query (R), roped query (pe), zeros
-    lat: jax.Array,  # [P, page, W] latent page pool: ckv (R), kpe (pe), zeros
+    lat: jax.Array,  # [L, P, page, W] latent pool: ckv (R), kpe (pe), zeros
     page_table: jax.Array,  # [B, NP] i32, -1 = unallocated
     pos: jax.Array,  # [B] i32 per-slot depth (position pos is attended)
+    layer: jax.Array,  # i32 scalar: the layer of the stack read
     *,
     scale: float,
     v_dim: int,
     interpret: bool = False,
 ) -> jax.Array:
-    """Paged absorbed-MLA decode attention -> [B, H, v_dim].
+    """Paged absorbed-MLA decode attention in layer ``layer`` of the
+    stacked latent pool -> [B, H, v_dim].
 
     Every query head of a slot attends the slot's one latent stream:
     scores ``q . lat`` over all W columns times ``scale``, values the first
@@ -361,7 +377,7 @@ def paged_latent_attn(
     step, the next attended block prefetched) and the f32 online softmax
     are those of :func:`paged_decode_attn`."""
     b, h, w = q.shape
-    _, page, _ = lat.shape
+    _, _, page, _ = lat.shape
     npg = page_table.shape[1]
     ppb = max(1, min(npg, _PAGED_BLOCK_BYTES
                      // (page * w * lat.dtype.itemsize)))
@@ -369,14 +385,14 @@ def paged_latent_attn(
     posr = jnp.asarray(pos, jnp.int32)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, pl.cdiv(npg, ppb)),
         in_specs=[
-            pl.BlockSpec((None, h, w), lambda i, j, pt, ps: (i, 0, 0)),
+            pl.BlockSpec((None, h, w), lambda i, j, *_: (i, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec(
-            (None, h, v_dim), lambda i, j, pt, ps: (i, 0, 0)
+            (None, h, v_dim), lambda i, j, *_: (i, 0, 0)
         ),
         scratch_shapes=[
             pltpu.VMEM((2, ppb, page, w), lat.dtype),
@@ -397,7 +413,7 @@ def paged_latent_attn(
             dimension_semantics=("arbitrary", "arbitrary")
         ),
         interpret=interpret,
-    )(pt, posr, q, lat)
+    )(pt, posr, _layer_operand(layer), q, lat)
 
 
 @functools.partial(jax.jit, static_argnames=("bt", "interpret"))

@@ -122,16 +122,18 @@ def paged_decode_attn(
     vp: jax.Array,
     page_table: jax.Array,
     pos: jax.Array,
+    layer: jax.Array,
     impl: Optional[str] = None,
 ) -> jax.Array:
-    """Decode attention through the paged KV pool (see
-    ``kernels.decode_attn.paged_decode_attn``): q [B,Hq,D], pool
-    [P,Hkv,page,D], page_table [B,NP] (-1 = unallocated), pos [B]."""
+    """Decode attention through layer ``layer`` of the stacked paged KV
+    pool (see ``kernels.decode_attn.paged_decode_attn``): q [B,Hq,D], pool
+    [L,P,Hkv,page,D], page_table [B,NP] (-1 = unallocated), pos [B]."""
     impl = resolve(impl)
     if impl == "ref":
-        return _ref.paged_decode_attn_ref(q, kp, vp, page_table, pos)
+        return _ref.paged_decode_attn_ref(q, kp[layer], vp[layer],
+                                          page_table, pos)
     return _da.paged_decode_attn(
-        q, kp, vp, page_table, pos, interpret=(impl == "interpret")
+        q, kp, vp, page_table, pos, layer, interpret=(impl == "interpret")
     )
 
 
@@ -140,21 +142,22 @@ def paged_latent_attn(
     lat: jax.Array,
     page_table: jax.Array,
     pos: jax.Array,
+    layer: jax.Array,
     *,
     scale: float,
     v_dim: int,
     impl: Optional[str] = None,
 ) -> jax.Array:
-    """Absorbed-MLA decode attention through the latent page pool (see
-    ``kernels.decode_attn.paged_latent_attn``): q [B,H,W], pool
-    [P,page,W], page_table [B,NP] (-1 = unallocated), pos [B] ->
-    [B,H,v_dim]."""
+    """Absorbed-MLA decode attention through layer ``layer`` of the stacked
+    latent page pool (see ``kernels.decode_attn.paged_latent_attn``): q
+    [B,H,W], pool [L,P,page,W], page_table [B,NP] (-1 = unallocated), pos
+    [B] -> [B,H,v_dim]."""
     impl = resolve(impl)
     if impl == "ref":
-        return _ref.paged_latent_attn_ref(q, lat, page_table, pos, scale,
-                                          v_dim)
+        return _ref.paged_latent_attn_ref(q, lat[layer], page_table, pos,
+                                          scale, v_dim)
     return _da.paged_latent_attn(
-        q, lat, page_table, pos, scale=scale, v_dim=v_dim,
+        q, lat, page_table, pos, layer, scale=scale, v_dim=v_dim,
         interpret=(impl == "interpret"),
     )
 

@@ -401,43 +401,64 @@ def gqa_paged_init_cache(
     return {"kp": jnp.zeros(shape, dtype), "vp": jnp.zeros(shape, dtype)}
 
 
+def _paged_write(pool: Array, layer: Array, page: Array, off: Array,
+                 rows: Array) -> Array:
+    """Write row b of ``rows`` at page ``page[b]``, offset ``off[b]`` of
+    layer ``layer`` of a stacked page pool: [L, P, kv, page, hd] at
+    ``[layer, page, :, off]`` (rows [B, kv, hd]) or [L, P, page, W] at
+    ``[layer, page, off]`` (rows [B, W]). A page of -1 (unallocated) drops
+    the write, so a freed slot can never scribble on a page that was
+    reallocated to someone else."""
+    # -1 must become one-past-end before the scatter: negative indices
+    # wrap numpy-style BEFORE mode="drop" filters, so a raw -1 would
+    # scribble on the pool's last page instead of dropping
+    page = jnp.where(page >= 0, page, pool.shape[1])
+    if pool.ndim == 5:
+        # one scatter index per (slot, kv head): each update is one
+        # minor-dim row of hd, which XLA writes in the pool's own layout.
+        # A [kv, hd] window across the page dim makes the TPU compiler lay
+        # the whole stack out page-row-major for the scatter and copy it
+        # back for the kernel, in every layer.
+        heads = jnp.arange(pool.shape[2])[None]
+        idx = (layer, page[:, None], heads, off[:, None])
+    else:
+        idx = (layer, page, off)
+    return pool.at[idx].set(rows.astype(pool.dtype), mode="drop")
+
+
 def gqa_paged_decode(
     x: Array, p: dict, cfg: ModelConfig, cache: dict, page_table: Array,
-    pos: Array,
+    pos: Array, layer: Array,
 ) -> tuple[Array, dict]:
-    """Single-token decode through the paged KV pool.
+    """Single-token decode of layer ``layer`` through the stacked paged KV
+    pool.
 
-    x [B,1,D]; cache {"kp","vp": [P, kv, page, hd]}; page_table [B, NP]
-    (physical page per logical block, -1 = unallocated — a write through
-    an unallocated entry is DROPPED, so a freed slot can never scribble on
-    a page that was reallocated to someone else); pos [B] per-slot depth.
+    x [B,1,D]; cache {"kp","vp": [L, P, kv, page, hd]}, every layer's
+    pool; page_table [B, NP] (physical page per logical block, -1 =
+    unallocated: the write is dropped, see :func:`_paged_write`); pos [B]
+    per-slot depth. Only the new token's row of layer ``layer`` is
+    written, so a decode step carrying the pool through its layer scan
+    keeps it in place.
 
     The ref path gathers the slot's pages back into the dense [B, T, ...]
     layout and runs the exact ``gqa_decode`` einsum chain (``_gqa_core``),
     so a paged engine at temperature 0 is BIT-identical to the dense one;
     the Pallas path (on a TPU, ``kernels.ops.default_impl``) streams pages
-    through ``kernels.ops.paged_decode_attn`` without materializing
-    [B, T, ...].
+    of layer ``layer`` through ``kernels.ops.paged_decode_attn`` without
+    materializing [B, T, ...].
     """
     from repro.kernels import ops as kops
 
-    ps = cache["kp"].shape[2]
+    ps = cache["kp"].shape[3]
     npages = page_table.shape[1]
     t = npages * ps
     b = x.shape[0]
     q, k, v = _qkv(x, p, cfg, pos[:, None])
-    bidx = jnp.arange(b)
-    page = page_table[bidx, pos // ps]  # [B]; -1 when unallocated/free
+    page = page_table[jnp.arange(b), pos // ps]  # [B]; -1 when unallocated
     off = pos % ps
-    # -1 must become one-past-end before the scatter: negative indices
-    # wrap numpy-style BEFORE mode="drop" filters, so a raw -1 would
-    # scribble on the pool's last page instead of dropping
-    page = jnp.where(page >= 0, page, cache["kp"].shape[0])
-    # [P, kv, page, hd] indexed (page, :, off): the advanced indices are
-    # split by a slice, so the update rows are [B, kv, hd] — k[:, 0] as is
     new_cache = {
-        "kp": cache["kp"].at[page, :, off].set(k[:, 0], mode="drop"),
-        "vp": cache["vp"].at[page, :, off].set(v[:, 0], mode="drop"),
+        "kp": _paged_write(cache["kp"], layer, page, off, k[:, 0]),
+        "vp": _paged_write(cache["vp"], layer, page, off, v[:, 0]),
     }
     impl = kops.default_impl()
     if impl == "ref":
@@ -446,10 +467,10 @@ def gqa_paged_decode(
         # softmax, so its weight underflows to exactly 0.0 — same as the
         # dense cache's own stale rows.
         pt = jnp.maximum(page_table, 0)  # clamp -1: masked anyway
-        kv_, hd = cache["kp"].shape[1], cache["kp"].shape[3]
+        kv_, hd = cache["kp"].shape[2], cache["kp"].shape[4]
 
         def dense(pool):  # [B, NP, kv, page, hd] -> [B, T, kv, hd]
-            return pool[pt].swapaxes(2, 3).reshape(b, t, kv_, hd)
+            return pool[layer, pt].swapaxes(2, 3).reshape(b, t, kv_, hd)
 
         ck, cv = dense(new_cache["kp"]), dense(new_cache["vp"])
         keep = (jnp.arange(t)[None] <= pos[:, None])[:, None]  # [B,1,T]
@@ -457,7 +478,7 @@ def gqa_paged_decode(
     else:
         o = kops.paged_decode_attn(
             q[:, 0], new_cache["kp"], new_cache["vp"], page_table, pos,
-            impl=impl,
+            layer, impl=impl,
         )
         out = o[:, None].astype(x.dtype)
     out = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
@@ -664,18 +685,19 @@ def mla_paged_init_cache(
 
 def mla_paged_decode(
     x: Array, p: dict, cfg: ModelConfig, cache: dict, page_table: Array,
-    pos: Array,
+    pos: Array, layer: Array,
 ) -> tuple[Array, dict]:
-    """Absorbed-weight decode through the latent page pool.
+    """Absorbed-weight decode of layer ``layer`` through the stacked latent
+    page pool.
 
-    x [B,1,D]; cache {"lat": [P, page, W]}; page_table [B, NP] (-1 =
-    unallocated: the write is dropped, as in ``gqa_paged_decode``); pos [B]
-    per-slot depth. The new token's latent is written at ``pos``; the query
-    absorbs wkv_b's K half ([B, H, R] beside the roped [B, H, pe], zeros
-    to W) and
-    attends one latent stream for all heads, whose first R columns are the
-    values (``kernels.ops.paged_latent_attn``); wkv_b's V half and wo then
-    apply to the weighted latent sum, as in :func:`mla_decode`."""
+    x [B,1,D]; cache {"lat": [L, P, page, W]}; page_table [B, NP] (-1 =
+    unallocated: the write is dropped, see :func:`_paged_write`); pos [B]
+    per-slot depth. The new token's latent is written at ``pos`` in layer
+    ``layer``; the query absorbs wkv_b's K half ([B, H, R] beside the
+    roped [B, H, pe], zeros to W) and attends one latent stream for all
+    heads, whose first R columns are the values
+    (``kernels.ops.paged_latent_attn``); wkv_b's V half and wo then apply
+    to the weighted latent sum, as in :func:`mla_decode`."""
     from repro.kernels import ops as kops
 
     dt = x.dtype
@@ -684,18 +706,15 @@ def mla_paged_decode(
     q_nope, q_pe = _mla_q(x, p, cfg, pos[:, None])
     ckv, kpe = _mla_kv_latent(x, p, cfg, pos[:, None])
     pool = cache["lat"]
-    ps, w = pool.shape[1], pool.shape[2]
+    ps, w = pool.shape[2], pool.shape[3]
     pad = [(0, 0), (0, 0), (0, w - r - cfg.qk_rope_head_dim)]
     page = page_table[jnp.arange(b), pos // ps]
-    # -1 -> one-past-end before the scatter (a raw -1 wraps, see
-    # gqa_paged_decode)
-    page = jnp.where(page >= 0, page, pool.shape[0])
     row = jnp.pad(jnp.concatenate([ckv, kpe], -1), pad)[:, 0]  # [B, W]
-    lat = pool.at[page, pos % ps].set(row.astype(pool.dtype), mode="drop")
+    lat = _paged_write(pool, layer, page, pos % ps, row)
     q_lat = jnp.einsum("bshk,rhk->bshr", q_nope,
                        p["wkv_b"][..., :nope].astype(dt))
     q = jnp.pad(jnp.concatenate([q_lat, q_pe], -1)[:, 0], pad)  # [B, H, W]
-    ctx = kops.paged_latent_attn(q, lat, page_table, pos,
+    ctx = kops.paged_latent_attn(q, lat, page_table, pos, layer,
                                  scale=mla_softmax_scale(cfg), v_dim=r)
     out = jnp.einsum("bhr,rhv->bhv", ctx.astype(dt),
                      p["wkv_b"][..., nope:].astype(dt))

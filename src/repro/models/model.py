@@ -503,14 +503,18 @@ def prefill(
     return logits, cache
 
 
-def _attn_block_decode(x, p, cfg, cache, pos, max_seq, ffn, page_table=None):
+def _attn_block_decode(x, p, cfg, cache, pos, max_seq, ffn, page_table=None,
+                       layer=None):
     """One decode layer -> (x, (cache, tokens routed to each expert [E],
-    None for a dense FFN))."""
+    None for a dense FFN)). Paged, ``cache`` is the whole stacked pool and
+    ``layer`` this layer's index in it."""
     h = L.rmsnorm(x, p["attn_norm"], cfg.norm_eps)
     if page_table is not None and cfg.attn_impl == "mla":
-        a, cache = L.mla_paged_decode(h, p["attn"], cfg, cache, page_table, pos)
+        a, cache = L.mla_paged_decode(h, p["attn"], cfg, cache, page_table,
+                                      pos, layer)
     elif page_table is not None:
-        a, cache = L.gqa_paged_decode(h, p["attn"], cfg, cache, page_table, pos)
+        a, cache = L.gqa_paged_decode(h, p["attn"], cfg, cache, page_table,
+                                      pos, layer)
     elif cfg.attn_impl == "mla":
         a, cache = L.mla_decode(h, p["attn"], cfg, cache, pos, max_seq)
     else:
@@ -531,6 +535,38 @@ def _ssm_block_decode(x, p, cfg, cache):
     return x + out, cache
 
 
+def _scan_attn_decode(x, params, cfg, cache, pos, ffn, page_table):
+    """Decode one stack of attention layers -> (x, its new cache, tokens
+    routed to each expert per layer [L, E] or None).
+
+    A per-slot cache is scanned layer by layer as xs/ys. A paged pool is
+    carried whole and each layer reads and writes its own index of it:
+    scanned as xs/ys, every step would slice each layer's pool out of the
+    stack, relay it out and back and restack it, then copy the whole pool
+    after the loop, where only the new token's rows need writing."""
+    if page_table is None:
+        max_seq = _attn_cache_capacity(cfg, cache)
+        body = lambda x, lpc: _attn_block_decode(
+            x, lpc[0], cfg, lpc[1], pos, max_seq, ffn
+        )
+        x, (cache, counts) = jax.lax.scan(body, x, (params, cache))
+        return x, cache, counts
+
+    def body(carry, lp):
+        x, pool = carry
+        layer, p = lp
+        x, (pool, counts) = _attn_block_decode(
+            x, p, cfg, pool, pos, 0, ffn, page_table, layer
+        )
+        return (x, pool), counts
+
+    n = jax.tree.leaves(params)[0].shape[0]
+    (x, cache), counts = jax.lax.scan(
+        body, (x, cache), (jnp.arange(n, dtype=jnp.int32), params)
+    )
+    return x, cache, counts
+
+
 def decode_step(
     params: dict, cfg: ModelConfig, cache: dict, tokens: Array, pos: Array,
     page_table: Optional[Array] = None, routing: bool = False,
@@ -546,6 +582,8 @@ def decode_step(
     ``page_table`` ([B, NP] i32, -1 = unallocated) switches the attention
     cache to the paged layout of :func:`init_paged_cache`: K/V writes and
     reads go through the table instead of a per-slot dense reservation.
+    The stacked pool is then carried through the layer scan, so a donated
+    pool stays in its buffer (:func:`_scan_attn_decode`).
 
     ``routing`` (MoE families) also returns the tokens each MoE layer
     routed to each expert, [L_moe, E] i32, every row of the batch counted.
@@ -562,20 +600,13 @@ def decode_step(
         )
     if cfg.family in ("dense", "audio", "vlm", "moe"):
         ffn = "moe" if cfg.family == "moe" else "dense"
-        max_seq = (0 if page_table is not None
-                   else _attn_cache_capacity(cfg, cache["blocks"]))
         if cfg.family == "moe" and cfg.first_k_dense:
-            body = lambda x, lpc: _attn_block_decode(
-                x, lpc[0], cfg, lpc[1], pos, max_seq, "dense", page_table
+            x, new_cache["dense_blocks"], _ = _scan_attn_decode(
+                x, params["dense_blocks"], cfg, cache["dense_blocks"], pos,
+                "dense", page_table,
             )
-            x, (new_cache["dense_blocks"], _) = jax.lax.scan(
-                body, x, (params["dense_blocks"], cache["dense_blocks"])
-            )
-        body = lambda x, lpc: _attn_block_decode(
-            x, lpc[0], cfg, lpc[1], pos, max_seq, ffn, page_table
-        )
-        x, (new_cache["blocks"], counts) = jax.lax.scan(
-            body, x, (params["blocks"], cache["blocks"])
+        x, new_cache["blocks"], counts = _scan_attn_decode(
+            x, params["blocks"], cfg, cache["blocks"], pos, ffn, page_table
         )
     elif cfg.family == "ssm":
         body = lambda x, lpc: _ssm_block_decode(x, lpc[0], cfg, lpc[1])

@@ -332,9 +332,9 @@ class Engine:
         # weights into the jaxpr as constants)
         self._decode = jax.jit(self._fused_step, donate_argnums=(1, 2))
         self._deliver = jax.jit(self._deliver_fn, donate_argnums=(0,))
-        # paged-mode host->device page-table maintenance (outside the
-        # transfer guard, like admission): scatter freshly grown pages /
-        # clear evicted rows, both at fixed [slots] shape with -1 padding
+        # host->device slot maintenance (outside the transfer guard, like
+        # admission): scatter freshly grown pages (paged mode) / free
+        # evicted slots, both at fixed [slots] shape with the padding
         # dropped so one compile serves any count
         self._grow_jit = jax.jit(self._grow_fn, donate_argnums=(0,))
         self._clear_jit = jax.jit(self._clear_fn, donate_argnums=(0,))
@@ -514,8 +514,11 @@ class Engine:
         return dataclasses.replace(estate, page_table=pt)
 
     def _clear_fn(self, estate, slots_arr):
+        inst = estate.inst.at[slots_arr].set(-1, mode="drop")
+        if estate.page_table is None:
+            return dataclasses.replace(estate, inst=inst)
         pt = estate.page_table.at[slots_arr].set(-1, mode="drop")
-        return dataclasses.replace(estate, page_table=pt)
+        return dataclasses.replace(estate, page_table=pt, inst=inst)
 
     # -- paged-cache host bookkeeping ----------------------------------------
 
@@ -728,13 +731,11 @@ class Engine:
     def _evict(self, done: list[tuple[int, int, int]]) -> None:
         # ONE batched fetch of every evicting slot's token rows (was one
         # device_get per slot); the per-slot :gen cut happens on host
+        freed = [s for _, s, _ in done]
         with self.telemetry.span("engine.evict_fetch", n=len(done)):
             rows = jax.device_get(
-                self._estate.out_toks[
-                    np.asarray([s for _, s, _ in done], np.int32)
-                ]
+                self._estate.out_toks[np.asarray(freed, np.int32)]
             )
-        cleared: list[int] = []
         for (inst, slot, gen), row in zip(done, np.asarray(rows)):
             self.finished[inst] = np.asarray(row[:gen])
             del self._slot_of[inst]
@@ -750,18 +751,18 @@ class Engine:
                     self._slot_reserve.pop(slot),
                 )
                 self._pos_host[slot] = 0
-                cleared.append(slot)
-        if cleared:
-            # clear the freed rows to -1 so the (still-resident-shaped)
-            # frozen K/V writes of a reused slot can never land in pages
-            # that have moved on to another owner; pad with one-past-end
-            # (a -1 pad would wrap to the last slot and wipe its row)
-            arr = np.full((self.slots,), self.slots, np.int32)
-            arr[: len(cleared)] = cleared
-            with self.telemetry.span("engine.clear"):
-                self._estate = self._clear_jit(
-                    self._estate, self.recorder.replicate(jnp.asarray(arr))
-                )
+        # mark the freed slots free on the device (inst -1: the next step
+        # reports them unoccupied) and, paged, clear their page-table rows
+        # to -1 so the (still-resident-shaped) frozen K/V writes of a
+        # reused slot can never land in pages that have moved on to another
+        # owner; pad with one-past-end (a -1 pad would wrap to the last
+        # slot and wipe its row)
+        arr = np.full((self.slots,), self.slots, np.int32)
+        arr[: len(freed)] = freed
+        with self.telemetry.span("engine.clear"):
+            self._estate = self._clear_jit(
+                self._estate, self.recorder.replicate(jnp.asarray(arr))
+            )
 
     def in_flight_ids(self) -> tuple[int, ...]:
         """Instance ids currently resident in a slot (admission order)."""

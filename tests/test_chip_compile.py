@@ -13,8 +13,11 @@ The last tests run on the CPU: they pin the dispatch rule that sends the
 main path to these kernels on a TPU.
 """
 
+import dataclasses
 import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -83,7 +86,7 @@ def test_topk_lse_compiles_at_full_vocab(one_chip, rows):
 def test_paged_decode_attn_compiles_at_qwen3_widths(one_chip, b, npg,
                                                     pool_pages, hq, hkv,
                                                     page):
-    pool = (pool_pages, hkv, page, QWEN.head_dim)
+    pool = (2, pool_pages, hkv, page, QWEN.head_dim)  # a stack of 2 layers
     text = _compiled_text(
         DA_mod.paged_decode_attn, one_chip,
         ((b, hq, QWEN.head_dim), jnp.bfloat16),
@@ -91,6 +94,7 @@ def test_paged_decode_attn_compiles_at_qwen3_widths(one_chip, b, npg,
         (pool, jnp.bfloat16),
         ((b, npg), jnp.int32),
         ((b,), jnp.int32),
+        ((), jnp.int32),
     )
     assert "tpu_custom_call" in text
 
@@ -100,7 +104,8 @@ DSV2 = configs.get("deepseek-v2-lite")  # published widths
 
 def test_paged_latent_attn_compiles_at_the_cell_shapes(one_chip):
     """The dsv2lite-rag cell: 64 slots of 240 pages (3,840 positions), a
-    15,360-page pool of rows of 640 (576 latent columns to 128 lanes)."""
+    15,360-page pool of rows of 640 (576 latent columns to 128 lanes), in
+    a stack of 2 layers."""
     from repro.models.layers import mla_latent_width, mla_softmax_scale
 
     w = mla_latent_width(DSV2)
@@ -109,11 +114,90 @@ def test_paged_latent_attn_compiles_at_the_cell_shapes(one_chip):
                           scale=mla_softmax_scale(DSV2),
                           v_dim=DSV2.kv_lora_rank), one_chip,
         ((64, DSV2.num_heads, w), jnp.bfloat16),
-        ((64 * 240, 16, w), jnp.bfloat16),
+        ((2, 64 * 240, 16, w), jnp.bfloat16),
         ((64, 240), jnp.int32),
         ((64,), jnp.int32),
+        ((), jnp.int32),
     )
     assert "paged_latent_attn" in text and "tpu_custom_call" in text
+
+
+def _big_arrays(text: str, elems: int) -> list[tuple[str, str, tuple]]:
+    """(name, opcode, shape) of every instruction outside a fusion body
+    whose array holds at least ``elems`` elements."""
+    fused = set(re.findall(r"fusion\(.*?calls=(%[\w.\-]+)", text))
+    comp, out = None, []
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?(%[\w.\-]+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        inst = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]*)\]\S* "
+                        r"([\w\-]+)\(", line)
+        if comp in fused or not inst:
+            continue
+        shape = tuple(int(d) for d in inst.group(2).split(",") if d)
+        if math.prod(shape) >= elems:
+            out.append((inst.group(1), inst.group(3), shape))
+    return out
+
+
+@pytest.mark.parametrize("arch,layers,pages,npg", [
+    # the qwen3-decode cell's pool: 4,096 pages of 16 for 64 slots of 64
+    ("qwen3-14b", 2, 4096, 64),
+    # dsv2lite-rag's: 15,360 pages for 64 slots of 240 (a dense first
+    # layer, then 2 MoE layers)
+    ("deepseek-v2-lite", 3, 15360, 240),
+])
+def test_paged_decode_step_keeps_the_pool_in_place(one_chip, monkeypatch,
+                                                   arch, layers, pages, npg):
+    """The fused step's paged decode, compiled for the chip at published
+    widths with the pool donated, carries the stacked pool through its
+    layer scan: no instruction makes an array of one layer's pool (a
+    slice, a relayout or a restack of it), and the step's temporaries
+    stay under one layer's pool. Only the in-place row writes, of the
+    stack's whole shape, and the routed experts' weights of one layer
+    (MoE), which the grouped matmuls read, are as large."""
+    from repro.models import model as Mdl
+    from repro.models.params import ParamSpec
+
+    monkeypatch.setattr(ops, "default_impl", lambda: "pallas")
+    cfg = dataclasses.replace(configs.get(arch), num_layers=layers)
+    slots, page = 64, 16
+    shaped = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    params = shaped(jax.tree.map(
+        lambda sp: jax.ShapeDtypeStruct(sp.shape, jnp.bfloat16),
+        Mdl.param_specs(cfg), is_leaf=lambda x: isinstance(x, ParamSpec)))
+    cache = shaped(jax.eval_shape(
+        lambda: Mdl.init_paged_cache(cfg, pages, page)))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one_chip)
+    step = jax.jit(lambda p, c, t, pos, pt: Mdl.decode_step(p, cfg, c, t,
+                                                            pos, pt),
+                   donate_argnums=(1,))
+    compiled = step.lower(params, cache, i32(slots, 1), i32(slots),
+                          i32(slots, npg)).compile()
+
+    # the stacks themselves; a stack of one layer is that layer's pool
+    stacks = {a.shape for a in jax.tree.leaves(cache)}
+    stacks |= {s[1:] for s in stacks if s[0] == 1}
+    pool = next(iter(jax.tree.leaves(cache["blocks"])))
+    slab = math.prod(pool.shape[1:])
+    experts = set()
+    if cfg.family == "moe":  # one layer's routed experts, [E, ...]
+        moe = Mdl.param_specs(cfg)["blocks"]["moe"]
+        experts = {moe[w].shape[1:] for w in ("w1", "w2", "w3")}
+    passes = {"parameter", "get-tuple-element", "tuple", "bitcast", "while"}
+    big = [(name, op, shape)
+           for name, op, shape in _big_arrays(compiled.as_text(), slab)
+           if op not in passes and shape not in stacks | experts]
+    assert big == []
+    slab_bytes = slab * pool.dtype.itemsize
+    expert_bytes = max((math.prod(e) * 2 for e in experts), default=0)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < slab_bytes + expert_bytes, (temp, slab_bytes)
 
 
 @pytest.mark.parametrize("tokens", [64, 3072], ids=["decode", "prefill"])
@@ -195,11 +279,12 @@ def test_tpu_main_path_calls_the_kernels(monkeypatch):
     params = materialize(Mdl.param_specs(cfg), jax.random.key(0),
                          jnp.float32)
     layer = jax.tree.map(lambda x: x[0], params["blocks"]["attn"])
-    cache = layers.gqa_paged_init_cache(cfg, 4, 8, jnp.float32)
+    cache = jax.tree.map(lambda a: a[None],
+                         layers.gqa_paged_init_cache(cfg, 4, 8, jnp.float32))
     x = jnp.ones((2, 1, cfg.d_model), jnp.float32)
     pt = jnp.asarray([[0, -1], [1, 2]], jnp.int32)
     layers.gqa_paged_decode(x, layer, cfg, cache, pt,
-                            jnp.asarray([3, 9], jnp.int32))
+                            jnp.asarray([3, 9], jnp.int32), jnp.int32(0))
     rec = OutcomeRecorder(2, 4, cfg.vocab_size, retention="topk", topk=8)
     rec.observe(rec.init_state(), jnp.zeros((2,), jnp.int32),
                 jnp.asarray(np.random.default_rng(0).normal(

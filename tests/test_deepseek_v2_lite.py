@@ -252,24 +252,34 @@ def test_plain_rope_configs_are_unchanged():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("page", [8, 16])
-def test_paged_latent_kernel_interpret_matches_ref(page):
+@pytest.mark.parametrize("page,layers,layer", [
+    pytest.param(8, 2, 1, id="8"),
+    pytest.param(16, 2, 1, id="16"),
+    pytest.param(16, 3, 0, id="16-first-of-3-layers"),
+    pytest.param(8, 3, 2, id="8-last-of-3-layers"),
+])
+def test_paged_latent_kernel_interpret_matches_ref(page, layers, layer):
     """The Pallas kernel in interpret mode against the jnp ref, in float32
     (so only the order of sums differs): a slot at depth 0, one over 30
     scattered pages (two page blocks at page 16, one at page 8), and a
-    free slot (no first page; reads zeros)."""
+    free slot (no first page; reads zeros). The pool is layer ``layer``
+    of a stack whose other layers hold NaN, so a read of any other layer
+    poisons the output."""
     b, h, w, vd, npg, pool = 3, 16, 640, 512, 40, 48
     q = jax.random.normal(jax.random.key(2), (b, h, w), jnp.float32)
-    lat = jax.random.normal(jax.random.key(3), (pool, page, w), jnp.float32)
+    lat = jnp.full((layers, pool, page, w), jnp.nan, jnp.float32)
+    lat = lat.at[layer].set(jax.random.normal(jax.random.key(3),
+                                              (pool, page, w), jnp.float32))
     perm = np.random.default_rng(0).permutation(pool)[:30]
     pt = np.full((b, npg), -1, np.int32)
     pt[0, 0] = 4
     pt[1, :30] = perm
     pos = jnp.asarray([0, 29 * page + 3, 0], jnp.int32)
     run = functools.partial(ops.paged_latent_attn, q, lat, jnp.asarray(pt),
-                            pos, scale=0.05, v_dim=vd)
+                            pos, jnp.int32(layer), scale=0.05, v_dim=vd)
     got = np.asarray(run(impl="interpret"))
     want = np.asarray(run(impl="ref"))
+    assert np.isfinite(want[:2]).all()
     np.testing.assert_allclose(got[:2], want[:2], atol=1e-5, rtol=1e-5)
     assert not got[2].any()
 

@@ -259,6 +259,13 @@ def test_paged_decode_attn_ref_equals_dense_gather(b, hq, hkv, d, page, npg):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
 
 
+def _in_stack(pool, layers, layer):
+    """``pool`` as layer ``layer`` of a stack of ``layers`` whose other
+    layers hold NaN: a page read from any other layer poisons the output."""
+    junk = jnp.full((layers,) + pool.shape, jnp.nan, pool.dtype)
+    return junk.at[layer].set(pool)
+
+
 def _bf16_ulp(x):
     """One bf16 ulp at |x| (8 significant bits)."""
     _, e = np.frexp(np.abs(x))
@@ -266,28 +273,42 @@ def _bf16_ulp(x):
 
 
 @pytest.mark.parametrize(
-    "b,hq,hkv,d,page,npg,dtype,extra",
+    "b,hq,hkv,d,page,npg,dtype,extra,layers,layer",
     [
-        pytest.param(2, 8, 2, 32, 16, 4, jnp.float32, 0, id="2-8-2-32-16-4"),
-        pytest.param(3, 4, 4, 64, 8, 5, jnp.float32, 0, id="3-4-4-64-8-5"),
-        pytest.param(2, 8, 8, 32, 16, 6, jnp.float32, 0, id="mha"),
-        pytest.param(3, 8, 1, 64, 16, 5, jnp.float32, 0, id="mqa"),
+        # layer L-1 of a stack of 2 whose layer 0 is NaN junk
+        pytest.param(2, 8, 2, 32, 16, 4, jnp.float32, 0, 2, 1,
+                     id="2-8-2-32-16-4"),
+        pytest.param(3, 4, 4, 64, 8, 5, jnp.float32, 0, 2, 1,
+                     id="3-4-4-64-8-5"),
+        pytest.param(2, 8, 8, 32, 16, 6, jnp.float32, 0, 2, 1, id="mha"),
+        pytest.param(3, 8, 1, 64, 16, 5, jnp.float32, 0, 2, 1, id="mqa"),
         # f32, 4 x 32 x 128: 64 KiB a page, 16 pages a block, 20 pages
-        pytest.param(3, 8, 4, 128, 32, 20, jnp.float32, 0,
+        pytest.param(3, 8, 4, 128, 32, 20, jnp.float32, 0, 2, 1,
                      id="np-not-a-multiple-of-the-block"),
-        pytest.param(3, 8, 2, 128, 16, 6, jnp.bfloat16, 0, id="bf16"),
-        pytest.param(3, 8, 2, 32, 8, 6, jnp.float32, 2,
+        pytest.param(3, 8, 2, 128, 16, 6, jnp.bfloat16, 0, 2, 1, id="bf16"),
+        pytest.param(3, 8, 2, 32, 8, 6, jnp.float32, 2, 2, 1,
                      id="pages-allocated-past-pos"),
+        # layer 0 and layer L-1 of a stack of 3, the others NaN junk
+        pytest.param(2, 8, 2, 32, 16, 4, jnp.float32, 0, 3, 0,
+                     id="first-of-3-layers"),
+        pytest.param(3, 8, 2, 128, 16, 6, jnp.bfloat16, 0, 3, 2,
+                     id="bf16-last-of-3-layers"),
+        pytest.param(3, 8, 2, 32, 8, 6, jnp.float32, 2, 1, 0,
+                     id="one-layer"),
     ],
 )
 def test_paged_decode_attn_kernel_matches_ref(b, hq, hkv, d, page, npg,
-                                              dtype, extra):
+                                              dtype, extra, layers, layer):
+    """The kernel reads layer ``layer`` of the stacked pool in place and
+    agrees with the ref on that layer's own pool."""
     q, kp, vp, pt, pos = _paged_case(b, hq, hkv, d, page, npg, dtype=dtype,
                                      extra=extra)
     ppb = DA_mod._pages_per_block(hkv, page, d, kp.dtype.itemsize, npg)
     if npg % ppb:  # the partial last block is attended by some row
         assert int(pos.max()) >= (npg // ppb) * ppb * page
-    out = DA_mod.paged_decode_attn(q, kp, vp, pt, pos, interpret=True)
+    out = DA_mod.paged_decode_attn(q, _in_stack(kp, layers, layer),
+                                   _in_stack(vp, layers, layer), pt, pos,
+                                   jnp.int32(layer), interpret=True)
     want = ref.paged_decode_attn_ref(q, kp, vp, pt, pos)
     assert out.dtype == want.dtype == dtype
     out = np.asarray(out, np.float32)
@@ -305,8 +326,9 @@ def test_paged_decode_attn_slot_without_pages():
     still equal the ref."""
     q, kp, vp, pt, pos = _paged_case(3, 8, 2, 32, 8, 6)
     pt = pt.at[1].set(-1)
-    out = np.asarray(DA_mod.paged_decode_attn(q, kp, vp, pt, pos,
-                                              interpret=True))
+    out = np.asarray(DA_mod.paged_decode_attn(
+        q, _in_stack(kp, 2, 0), _in_stack(vp, 2, 0), pt, pos, 0,
+        interpret=True))
     want = np.asarray(ref.paged_decode_attn_ref(q, kp, vp, pt, pos))
     assert np.isfinite(out[1]).all()
     np.testing.assert_allclose(out[[0, 2]], want[[0, 2]], atol=2e-6)
@@ -314,8 +336,9 @@ def test_paged_decode_attn_slot_without_pages():
 
 def test_paged_decode_attn_ops_dispatch():
     q, kp, vp, pt, pos = _paged_case(1, 4, 2, 32, 8, 3)
-    r = ops.paged_decode_attn(q, kp, vp, pt, pos, impl="ref")
-    i = ops.paged_decode_attn(q, kp, vp, pt, pos, impl="interpret")
+    kp, vp = _in_stack(kp, 2, 1), _in_stack(vp, 2, 1)
+    r = ops.paged_decode_attn(q, kp, vp, pt, pos, 1, impl="ref")
+    i = ops.paged_decode_attn(q, kp, vp, pt, pos, 1, impl="interpret")
     np.testing.assert_allclose(np.asarray(r), np.asarray(i), atol=2e-6)
 
 

@@ -455,6 +455,65 @@ def test_paged_engine_bit_identical_to_dense(params, page_size):
     assert st["pages_reserved"] == 0
 
 
+@pytest.mark.parametrize("arch", ["llama3-8b", "deepseek-v2-lite"])
+def test_paged_decode_step_writes_only_the_new_rows(arch):
+    """One paged ``decode_step`` (ref path) on a pool whose every element
+    holds distinct junk: in each layer's own slab it changes exactly the
+    new token's row of every slot with a page at its depth, and leaves
+    every other row — of every layer, slot and page, the pool's last page
+    under a dropped (-1) write included — bit-for-bit as it was. GQA pools
+    (llama) and latent pools with a dense first layer (DeepSeek) alike."""
+    cfg = configs.get_smoke(arch)
+    params = materialize(Mdl.param_specs(cfg), jax.random.key(3),
+                         jnp.float32)
+    pages, ps = 10, 4
+    cache = Mdl.init_paged_cache(cfg, pages, ps)
+    cache = jax.tree.map(
+        lambda a: -jnp.arange(a.size, dtype=a.dtype).reshape(a.shape)
+        / a.size, cache)
+    # slot 0 writes page 5 at 1, slot 1's depth has no page (dropped),
+    # slot 2 writes page 3 at 2, slot 3 is free
+    pt = jnp.asarray([[2, 5, -1], [7, -1, -1], [0, 1, 3], [-1, -1, -1]],
+                     jnp.int32)
+    pos = jnp.asarray([5, 4, 10, 0], jnp.int32)
+    toks = jnp.asarray([[1], [2], [3], [4]], jnp.int32)
+    before = jax.tree.map(np.asarray, cache)
+    _, after = Mdl.decode_step(params, cfg, cache, toks, pos, pt)
+    for name, stack in before.items():
+        for leaf, old in stack.items():
+            new = np.asarray(after[name][leaf])
+            want = np.zeros(old.shape, bool)  # the rows this step writes
+            for page, off in ((5, 1), (3, 2)):
+                if old.ndim == 5:  # [L, P, kv, page, hd]
+                    want[:, page, :, off] = True
+                else:  # [L, P, page, W]
+                    want[:, page, off] = True
+            changed = new != old
+            # every written row holds something new, in every layer
+            rows = changed.any(-1)
+            np.testing.assert_array_equal(rows, want.any(-1),
+                                          err_msg=f"{name}/{leaf}")
+            np.testing.assert_array_equal(new[~want], old[~want],
+                                          err_msg=f"{name}/{leaf}")
+
+
+@pytest.mark.parametrize("page_size", [None, 2], ids=["dense", "paged"])
+def test_evicted_slots_read_unoccupied(params, page_size):
+    """A slot freed by eviction is free on the device too (inst -1): every
+    step's ``occupied`` is exactly the slots resident on the host, so a
+    reader of the step's metrics (the paged kernel's costs) never counts a
+    finished request's positions again while its slot waits for reuse."""
+    eng = make_engine(params, slots=4, page_size=page_size)
+    for prompt, gen, labels in random_requests(7, seed=5):
+        eng.submit(prompt, max_new=gen, labels=labels)
+    steps = 0
+    while (m := eng.step()) is not None:
+        steps += 1
+        np.testing.assert_array_equal(np.flatnonzero(m["occupied"]),
+                                      sorted(eng._slot_of.values()))
+    assert steps and eng.stats()["evicted"] == 7
+
+
 def test_paged_pool_exhaustion_defers_and_preserves_results(params):
     """A pool sized for ~2 worst-case residents under a 4-slot engine must
     defer admissions (never touch a live slot) and still produce the same
